@@ -33,8 +33,6 @@ import time
 
 import numpy as np
 
-from benchmarks.jax_cache import enable_persistent_cache
-
 from repro.continuum import (
     CarbonTrace,
     ContinuumRuntime,
@@ -57,6 +55,7 @@ from repro.core.types import (
     NodeCapabilities,
     Service,
 )
+from repro.jax_cache import enable_persistent_cache
 from repro.obs import metrics_scope
 
 OUT_JSON = "BENCH_continuum.json"

@@ -44,7 +44,6 @@ import time
 
 import numpy as np
 
-from benchmarks.jax_cache import enable_persistent_cache
 from benchmarks.continuum_loop import OUT_JSON, _carbon_planner, build_scenario
 
 from repro.continuum import (
@@ -57,6 +56,7 @@ from repro.continuum import (
 )
 from repro.core.pipeline import GreenConstraintPipeline
 from repro.faults import FaultEvent, FaultTrace
+from repro.jax_cache import enable_persistent_cache
 
 REGIONS = ("solar-south", "wind-north", "coal-east")
 # Decision/accounting fields that must be IDENTICAL between the eager

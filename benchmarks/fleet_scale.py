@@ -29,12 +29,12 @@ import json
 import os
 import time
 
-from benchmarks.jax_cache import enable_persistent_cache
 from benchmarks.scheduler_scalability import synth
 
 from repro.core.problem import PlacementProblem
 from repro.core.scheduler import GreenScheduler, SchedulerConfig
 from repro.fleet import FleetProblem, plan_many
+from repro.jax_cache import enable_persistent_cache
 from repro.obs import metrics_scope
 
 OUT_JSON = "BENCH_scheduler.json"

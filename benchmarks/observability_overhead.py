@@ -34,8 +34,6 @@ import json
 import os
 import time
 
-from benchmarks.jax_cache import enable_persistent_cache
-
 from benchmarks.continuum_loop import (
     OUT_JSON as CONTINUUM_JSON,
     _carbon_planner,
@@ -49,6 +47,7 @@ from repro.continuum import (
     WorkloadTrace,
 )
 from repro.core.pipeline import GreenConstraintPipeline
+from repro.jax_cache import enable_persistent_cache
 from repro.obs import Observability, SLO, Watchtower, metrics_scope
 
 OUT_JSON = "BENCH_observability.json"

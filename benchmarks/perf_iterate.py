@@ -15,7 +15,7 @@ import time
 import jax
 
 from repro.launch import hlo_cost, hlo_analysis
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.plan import build_plan
 
 
@@ -38,7 +38,7 @@ def lower_cell(arch, shape, overrides, multi_pod=False):
     mesh = make_production_mesh(multi_pod=multi_pod)
     plan = build_plan(arch, shape, multi_pod=multi_pod,
                       tuning_overrides=overrides or None)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = plan.lower().compile()
         txt = compiled.as_text()
         mem = compiled.memory_analysis()

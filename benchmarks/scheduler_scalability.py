@@ -42,8 +42,6 @@ import random
 import sys
 import time
 
-from benchmarks.jax_cache import enable_persistent_cache
-
 from repro.core.lowering import SPARSE_AUTO_THRESHOLD, lower
 from repro.core.problem import BucketSpec, PlacementProblem
 from repro.core.scheduler import (
@@ -52,6 +50,7 @@ from repro.core.scheduler import (
     SchedulerConfig,
     reference_objective,
 )
+from repro.jax_cache import enable_persistent_cache
 from repro.obs import metrics_scope
 from repro.core.types import (
     Affinity,

@@ -33,6 +33,7 @@ from repro.core.types import (
     NodeCapabilities,
     Service,
 )
+from repro.jax_cache import enable_persistent_cache
 
 START, DAYS = 24, 3
 
@@ -66,6 +67,7 @@ def run_policy(app, infra, carbon, workload, config):
 
 
 def main():
+    enable_persistent_cache()
     app, infra = build_app(), build_infra()
     carbon = CarbonTrace(REGION_PRESETS, hours=START + DAYS * 24 + 25,
                          seed=42)

@@ -33,6 +33,7 @@ from repro.models.sharding import init_from_schema
 from repro.models.testing import reduced
 from repro.optim import adamw
 from repro.train.steps import make_train_step
+from repro.jax_cache import enable_persistent_cache
 
 CKPT = "/tmp/repro_elastic_demo"
 ROOF = {"perf": {"compute_s": 1.2, "memory_s": 8.5, "collective_s": 3.9}}
@@ -47,6 +48,7 @@ def place(pods):
 
 
 def main():
+    enable_persistent_cache()
     shutil.rmtree(CKPT, ignore_errors=True)
     pods = [
         PodSpec("pod-a", "finland", carbon=80.0, cost_per_chip_hour=1.0),

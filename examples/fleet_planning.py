@@ -43,6 +43,7 @@ from repro.core.types import (
 )
 from repro.fleet import FleetApp, FleetProblem, FleetRuntime, plan_many
 from repro.obs import Observability, billing_report, render_billing
+from repro.jax_cache import enable_persistent_cache
 
 
 def tenant_app(tag: str, n_services: int) -> Application:
@@ -66,6 +67,7 @@ def shared_infra(carbon_by_region=None) -> Infrastructure:
 
 
 def main() -> None:
+    enable_persistent_cache()
     infra = shared_infra()
     carbon = CarbonTrace(REGION_PRESETS, hours=48, seed=11)
     sched = GreenScheduler(SchedulerConfig(emission_weight=1.0))

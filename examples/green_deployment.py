@@ -21,6 +21,7 @@ from repro.launch.green_placement import (
     PodSpec,
     TrafficSpec,
 )
+from repro.jax_cache import enable_persistent_cache
 
 DRYRUN = os.path.join(os.path.dirname(__file__), "..",
                       "dryrun_results.jsonl")
@@ -57,6 +58,7 @@ def roofline_lookup():
 
 
 def main():
+    enable_persistent_cache()
     roof = roofline_lookup()
 
     def flavours(arch, shape, scale_eco=0.55):

@@ -44,6 +44,7 @@ from repro.core.types import (
     NodeCapabilities,
     Service,
 )
+from repro.jax_cache import enable_persistent_cache
 
 START, TICKS = 24, 48
 
@@ -66,6 +67,7 @@ def build():
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dump", metavar="PATH", default=None,
                     help="write the deterministic trace as a "

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.configs import boutique
 from repro.core.pipeline import GreenConstraintPipeline
 from repro.core.scheduler import GreenScheduler, SchedulerConfig, plan_emissions
+from repro.jax_cache import enable_persistent_cache
 
 
 def emissions_of(plan, app, infra, comp, comm):
@@ -23,6 +24,7 @@ def emissions_of(plan, app, infra, comp, comm):
 
 
 def main():
+    enable_persistent_cache()
     # ---- iteration 1: Scenario 1 (Europe) --------------------------------
     app, infra, mon = boutique.scenario(1)
     pipe = GreenConstraintPipeline()

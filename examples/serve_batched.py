@@ -22,6 +22,7 @@ from repro.launch.serve import serve_batch
 from repro.models.schema import build_schema
 from repro.models.sharding import init_from_schema
 from repro.models.testing import reduced
+from repro.jax_cache import enable_persistent_cache
 
 
 def continuous_batching_demo():
@@ -46,6 +47,7 @@ def continuous_batching_demo():
 
 
 def main():
+    enable_persistent_cache()
     for arch in ("qwen2-1.5b", "falcon-mamba-7b", "zamba2-1.2b"):
         cfg = reduced(get_arch(arch))
         params = init_from_schema(jax.random.PRNGKey(0),

@@ -28,6 +28,7 @@ from repro.models.schema import build_schema
 from repro.models.sharding import init_from_schema
 from repro.optim import adamw
 from repro.train.steps import make_train_step
+from repro.jax_cache import enable_persistent_cache
 
 
 def model_config(hundred_m: bool):
@@ -44,6 +45,7 @@ def model_config(hundred_m: bool):
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hundred-m", action="store_true")
     ap.add_argument("--steps", type=int, default=300)

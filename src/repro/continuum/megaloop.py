@@ -1350,7 +1350,6 @@ def run_scanned(runtime, start: int, ticks: int):
     stage_s = time.perf_counter() - t0
 
     import jax
-    from jax.experimental import enable_x64
 
     with_metrics = obs is not None
     with_watch = watch is not None
@@ -1365,7 +1364,7 @@ def run_scanned(runtime, start: int, ticks: int):
         carry0 = carry0 + (watch.scan_carry(st.N, st.S),)
     wconsts = watch.scan_consts() if with_watch else ()
     t1 = time.perf_counter()
-    with enable_x64():
+    with jax.enable_x64(True):
         carry_out, ys = fn(carry0, st.xs, st.consts, wconsts)
         ys = jax.block_until_ready(ys)
     scan_s = time.perf_counter() - t1
@@ -1422,7 +1421,6 @@ def monte_carlo_emissions(runtime, start: int, ticks: int, ci_scales):
         gatherer.signal, gatherer.forecast = saved
 
     import jax
-    from jax.experimental import enable_x64
 
     scales = np.asarray(ci_scales, float).reshape(-1)
     M = scales.size
@@ -1438,7 +1436,7 @@ def monte_carlo_emissions(runtime, start: int, ticks: int, ci_scales):
             None)
     fn = _scan_fn(st.kind)
     vfn = jax.vmap(fn, in_axes=(None, axes, None, None))
-    with enable_x64():
+    with jax.enable_x64(True):
         _, ys = vfn(st.carry0, xs_m, st.consts, ())
         ys = jax.block_until_ready(ys)
     em = np.asarray(ys[11])          # [M, T] operational

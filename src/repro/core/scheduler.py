@@ -693,7 +693,7 @@ class GreenScheduler:
                                        and stat_feas_real is not None) \
             else _static_feasibility(plow)
 
-        from jax.experimental import enable_x64
+        import jax
 
         planner = _batched_planner(plow.comm.kind)
         sig = (plow.comm.kind,) + padded_shape
@@ -702,7 +702,7 @@ class GreenScheduler:
         # threshold in rounding noise and let the local search ping-pong
         # on near-ties.
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = planner(
                 ci_b, ci_mean_b, E_b, order_b, *warm,
                 *plow.comm.planner_args(), P, A, stat_feas,

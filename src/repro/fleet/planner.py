@@ -26,15 +26,17 @@ Coupling over the SHARED node capacity (see ``fleet.problem``):
   penalty scale), prices raised on over-committed nodes between rounds.
   Keeps full app parallelism; residual violations are reported.
 
-When more than one device is visible, the uncoupled/price programs are
-``shard_map``-ed over the app axis (apps are embarrassingly parallel);
-a single device falls back to the plain jit(vmap) program.
+The caller picks the devices (``plan_many(..., devices=...)``, default
+every visible device).  On more than one, the uncoupled/price programs
+are ``shard_map``-ed over the app axis (apps are embarrassingly
+parallel), with the app axis padded by phantom apps to a multiple of the
+device count; on one, the plain jit(vmap) program runs on that device.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -77,7 +79,7 @@ __all__ = ["plan_many"]
 # cache; COMPILE_CACHE mirrors the signatures for observability).
 _UNCOUPLED_CACHE: Dict[str, object] = {}
 _WATERFILL_CACHE: Dict[str, object] = {}
-_SHARDED_CACHE: Dict[Tuple[str, int], object] = {}
+_SHARDED_CACHE: Dict[Tuple, object] = {}
 
 _WF_WARM_NOTE = ("warm start rejected (capacity claimed by "
                  "higher-priority tenants); rebuilt from scratch")
@@ -109,25 +111,24 @@ def _uncoupled_program(kind: str):
     return fn
 
 
-def _sharded_program(kind: str, n_dev: int):
+def _sharded_program(kind: str, devices: Tuple):
     """The uncoupled program shard_map-ed over the app axis: each device
     plans its slice of apps with the full (replicated) infrastructure."""
-    key = (kind, n_dev)
+    key = (kind,) + tuple(d.id for d in devices)
     if key in _SHARDED_CACHE:
         return _SHARDED_CACHE[key]
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     axes = _app_axes(PLANNER_COMM_ARGC[kind])
-    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("apps",))
+    mesh = Mesh(np.array(devices), ("apps",))
     in_specs = tuple(
         PartitionSpec("apps") if a == 0 else PartitionSpec()
         for a in axes)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         jax.vmap(planner_single(kind), in_axes=axes),
         mesh=mesh, in_specs=in_specs,
-        out_specs=PartitionSpec("apps"), check_rep=False))
+        out_specs=PartitionSpec("apps"), check_vma=False))
     _SHARDED_CACHE[key] = fn
     return fn
 
@@ -361,39 +362,37 @@ def _chunks(seq: List[_Prep], size: int):
 
 
 def _run_group(kind: str, preps: List[_Prep], bucket: BucketSpec, cfg,
-               max_batch: int, n_dev: int, stats: FleetStats,
+               max_batch: int, devices: Tuple, stats: FleetStats,
                green_pen: Optional[float] = None,
                penalties: Optional[List] = None) -> None:
     """Run one same-shape group through the uncoupled program, chunked
     along the app axis; writes each prep's ``out`` row in place."""
-    from jax.experimental import enable_x64
+    import jax
 
     gp = cfg.green_penalty if green_pen is None else green_pen
     argc = PLANNER_COMM_ARGC[kind]
+    n_dev = len(devices)
+    use_shard = n_dev > 1
+    fn = _sharded_program(kind, devices) if use_shard \
+        else _uncoupled_program(kind)
     pos = 0
     for chunk in _chunks(preps, max_batch):
         pens = penalties[pos:pos + len(chunk)] if penalties else None
         pos += len(chunk)
         A_real = len(chunk)
-        A_chunk = bucket.pad_apps(A_real)
-        use_shard = n_dev > 1
-        if use_shard:
-            A_chunk = max(A_chunk, n_dev)
-            if A_chunk % n_dev:
-                use_shard = False
+        # phantom apps fill the app axis up to a multiple of the devices
+        A_chunk = -(-bucket.pad_apps(A_real) // n_dev) * n_dev
         shared, stacked = _chunk_args(chunk, A_chunk, pens)
         ci, ci_mean, cpu_cap, ram_cap, cost = shared
         E, order = stacked[:2]
         wp, wf, wn, wcpu, wram = stacked[2:7]
         comm = stacked[7:7 + argc]
         P_s, A_s, sf_s, cpur, ramr, must_s, ms = stacked[7 + argc:]
-        fn = _sharded_program(kind, n_dev) if use_shard \
-            else _uncoupled_program(kind)
         dims = chunk[0].dims
         sig = ("fleet", kind, A_chunk) + dims + (
             (n_dev,) if use_shard else ())
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(devices[0]):
             out = fn(ci, ci_mean, E, order, wp, wf, wn, wcpu, wram,
                      *comm, P_s, A_s, sf_s, cpur, ramr, cpu_cap, ram_cap,
                      must_s, cost, cfg.money_weight, cfg.pref_weight,
@@ -405,19 +404,20 @@ def _run_group(kind: str, preps: List[_Prep], bucket: BucketSpec, cfg,
         stats.compiles += int(compiled)
         stats.plan_time_s += dt
         stats.padded_apps += A_chunk - A_real
-        stats.sharded = stats.sharded or use_shard
+        stats.sharded = use_shard
         for i, prep in enumerate(chunk):
             prep.out = tuple(o[i] for o in outs)
             prep.sig, prep.plan_time_s, prep.compiled = sig, dt, compiled
 
 
 def _run_waterfill(fleet: FleetProblem, preps: List[_Prep],
-                   bucket: BucketSpec, cfg, max_batch: int,
+                   bucket: BucketSpec, cfg, max_batch: int, device,
                    stats: FleetStats) -> None:
     """Priority-ordered waterfill over all apps (one shared padded shape),
     chunked along the app axis with the node-load carry threaded across
-    chunks host-side."""
-    from jax.experimental import enable_x64
+    chunks host-side.  The scan is sequential over apps, so it runs on
+    one device."""
+    import jax
 
     kind = preps[0].low.comm.kind
     argc = PLANNER_COMM_ARGC[kind]
@@ -436,7 +436,7 @@ def _run_waterfill(fleet: FleetProblem, preps: List[_Prep],
         dims = chunk[0].dims
         sig = ("fleet_wf", kind, A_chunk) + dims
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(device):
             cpu_out, ram_out, ys = fn(
                 cpu_used, ram_used, ci, ci_mean, cpu_cap, ram_cap, cost,
                 cfg.money_weight, cfg.pref_weight, cfg.emission_weight,
@@ -503,7 +503,7 @@ def _price_penalties(prep: _Prep, lam_cpu: np.ndarray, lam_ram: np.ndarray,
 
 
 def _run_price(fleet: FleetProblem, groups: Dict[Tuple, List[_Prep]],
-               bucket: BucketSpec, cfg, max_batch: int, n_dev: int,
+               bucket: BucketSpec, cfg, max_batch: int, devices: Tuple,
                stats: FleetStats) -> None:
     ref = fleet.apps[0].lowering
     N = ref.N
@@ -518,7 +518,7 @@ def _run_price(fleet: FleetProblem, groups: Dict[Tuple, List[_Prep]],
         for (kind, *_dims), preps in groups.items():
             pens = [_price_penalties(p, lam_cpu, lam_ram, gp, gp_eff)
                     for p in preps]
-            _run_group(kind, preps, bucket, cfg, max_batch, n_dev, stats,
+            _run_group(kind, preps, bucket, cfg, max_batch, devices, stats,
                        green_pen=gp_eff, penalties=pens)
         stats.price_rounds += 1
         cpu_load, ram_load = _loads_from_preps(all_preps, N)
@@ -584,7 +584,8 @@ def _finalize(prep: _Prep) -> PlanResult:
 def plan_many(fleet: FleetProblem,
               scheduler: Optional[GreenScheduler] = None, *,
               bucket: Optional[BucketSpec] = None,
-              max_batch: int = 256) -> FleetResult:
+              max_batch: int = 256,
+              devices: Optional[Sequence] = None) -> FleetResult:
     """Plan every app of a :class:`FleetProblem` as batched programs.
 
     ``scheduler`` supplies the objective configuration (defaults to a
@@ -592,7 +593,10 @@ def plan_many(fleet: FleetProblem,
     per-app dims and the app axis (defaults to the scheduler's bucket,
     else pow2).  ``max_batch`` bounds apps per program execution —
     equal-size chunks reuse one compiled program, so the bound trades
-    peak memory against dispatch count, not compiles.
+    peak memory against dispatch count, not compiles.  ``devices`` are
+    the jax devices the programs run on (default ``jax.devices()``): the
+    uncoupled and price programs shard the app axis over all of them,
+    the waterfill scan runs on the first.
 
     Returns a :class:`FleetResult` with one B=1 ``PlanResult`` per app
     (same order as ``fleet.apps``), per-app emissions, the shared-node
@@ -614,8 +618,9 @@ def plan_many(fleet: FleetProblem,
 
     import jax
 
-    n_dev = len(jax.devices())
-    stats.devices = n_dev
+    devices = tuple(jax.devices() if devices is None else devices)
+    if not devices:
+        raise ValueError("plan_many needs at least one device")
 
     # Shape-degenerate apps (no services / no nodes) take the scheduler's
     # host path — nothing to batch, nothing consumed.
@@ -632,7 +637,9 @@ def plan_many(fleet: FleetProblem,
             preps = [_prep_app(i, p, cfg, bucket, dims)
                      for i, p in batched]
             stats.groups = 1
-            _run_waterfill(fleet, preps, bucket, cfg, max_batch, stats)
+            stats.devices = 1
+            _run_waterfill(fleet, preps, bucket, cfg, max_batch,
+                           devices[0], stats)
         else:
             preps = [_prep_app(i, p, cfg, bucket) for i, p in batched]
             groups: Dict[Tuple, List[_Prep]] = {}
@@ -640,12 +647,13 @@ def plan_many(fleet: FleetProblem,
                 key = (prep.low.comm.kind,) + prep.dims
                 groups.setdefault(key, []).append(prep)
             stats.groups = len(groups)
+            stats.devices = len(devices)
             if fleet.coupling == "price":
-                _run_price(fleet, groups, bucket, cfg, max_batch, n_dev,
+                _run_price(fleet, groups, bucket, cfg, max_batch, devices,
                            stats)
             else:
                 for (kind, *_dims), grp in groups.items():
-                    _run_group(kind, grp, bucket, cfg, max_batch, n_dev,
+                    _run_group(kind, grp, bucket, cfg, max_batch, devices,
                                stats)
         for prep in preps:
             results[prep.idx] = _finalize(prep)

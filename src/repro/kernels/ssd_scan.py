@@ -16,7 +16,10 @@ On TPU the natural mapping is:
     assigned archs, so all tiles are MXU-aligned (multiples of (8, 128)
     after padding) and the working set is < 1 MiB;
   * the decay matrix L = exp(segsum(dt*A)) is built in-register from a
-    cumulative sum — no HBM materialisation of the (Q, Q) mask.
+    cumulative sum taken as a triangular contraction — no HBM
+    materialisation of the (Q, Q) mask;
+  * dt arrives twice, as a (1, Q) row and a (Q, 1) column block, and the
+    per-head A as a scalar in SMEM, so every block is tile-aligned.
 
 The final state is emitted so prefill can hand the cache to decode.
 """
@@ -35,9 +38,9 @@ NEG_INF = -1e30
 
 
 def _ssd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref,    # inputs
-    y_ref, hfin_ref,                        # outputs
-    h_scr,                                  # (hp, n) carried state
+    x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref,    # inputs
+    y_ref, hfin_ref,                                  # outputs
+    h_scr,                                            # (hp, n) carried state
     *,
     chunk: int,
     num_chunks: int,
@@ -49,24 +52,34 @@ def _ssd_kernel(
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, hp)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
-    A = a_ref[0].astype(jnp.float32)             # scalar (this head)
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)   # (1, Q)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)   # (Q, 1)
+    A = a_ref[pl.program_id(1)]                  # scalar (this head), SMEM
     Bm = b_ref[0].astype(jnp.float32)            # (Q, n)
     Cm = c_ref[0].astype(jnp.float32)            # (Q, n)
 
-    dtA = dt * A                                  # (Q,)
-    cum = jnp.cumsum(dtA)                         # (Q,)
-
-    # intra-chunk: y[q] += sum_{k<=q} exp(cum[q]-cum[k]) (C_q.B_k) dt_k x_k
-    seg = cum[:, None] - cum[None, :]             # (Q, Q)
+    # cum[q] = sum_{k<=q} dt_k A, as triangular contractions (no cumsum on
+    # the TPU's vector unit), once as a row and once as a column
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(qi >= ki, jnp.exp(seg), 0.0)    # lower-tri decay
+    lower = qi >= ki
+    tri = lower.astype(jnp.float32)               # tri[q, k] = k <= q
+    hi = jax.lax.Precision.HIGHEST
+    cum_col = jax.lax.dot_general(
+        tri, dt_col * A, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)      # (Q, 1)
+    cum_row = jax.lax.dot_general(
+        dt_row * A, tri, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)      # (1, Q)
+    total = jnp.sum(dt_row * A, axis=1, keepdims=True)   # (1, 1)
+
+    # intra-chunk: y[q] += sum_{k<=q} exp(cum[q]-cum[k]) (C_q.B_k) dt_k x_k
+    L = jnp.where(lower, jnp.exp(cum_col - cum_row), 0.0)   # lower-tri decay
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                             # (Q, Q) = C_q . B_k
-    w = L * scores * dt[None, :]
+    w = L * scores * dt_row
     y = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -74,19 +87,19 @@ def _ssd_kernel(
 
     # inter-chunk: y[q] += exp(cum[q]) C_q . h_prev      (h_prev: (hp, n))
     h_prev = h_scr[...]
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum_col) * jax.lax.dot_general(
         Cm, h_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     # state update: h = exp(cum[-1]) h_prev
     #                  + sum_k exp(cum[-1]-cum[k]) dt_k x_k B_k^T
-    decay_to_end = jnp.exp(cum[-1] - cum) * dt    # (Q,)
+    decay_to_end = jnp.exp(total - cum_col) * dt_col    # (Q, 1)
     upd = jax.lax.dot_general(
-        x * decay_to_end[:, None], Bm, (((0,), (0,)), ((), ())),
+        x * decay_to_end, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                             # (hp, n)
-    h_scr[...] = jnp.exp(cum[-1]) * h_prev + upd
+    h_scr[...] = jnp.exp(total) * h_prev + upd
 
     y_ref[0, 0, :, :] = y.astype(y_ref.dtype)
 
@@ -124,8 +137,11 @@ def ssd_scan_bhsp(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, hp), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            # dt as a (1, Q) row and a (Q, 1) column: both layouts keep the
+            # block's last two dims tile-aligned or full
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # A: all heads
             pl.BlockSpec((1, chunk, n), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, h, c: (b, c, 0)),
         ],
@@ -139,5 +155,5 @@ def ssd_scan_bhsp(
         ],
         scratch_shapes=[pltpu.VMEM((hp, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bc, Cc)
+    )(x, dt[:, :, None, :], dt[..., None], A.astype(jnp.float32), Bc, Cc)
     return y, hfin

@@ -23,7 +23,7 @@ import jax
 
 from repro.configs.registry import ARCHS
 from repro.launch import hlo_analysis, hlo_cost
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.plan import build_plan
 from repro.models.config import SHAPES, cell_is_supported
 from repro.obs import Tracer
@@ -58,7 +58,7 @@ def run_cell(
             plan = build_plan(arch, shape, multi_pod=multi_pod,
                               tuning_overrides=tuning_overrides,
                               optimized=optimized)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             with tracer.span("dryrun.lower"):
                 lowered = plan.lower()
             with tracer.span("dryrun.compile"):
@@ -66,8 +66,6 @@ def run_cell(
             with tracer.span("dryrun.analyze"):
                 mem = compiled.memory_analysis()
                 xla_cost = compiled.cost_analysis() or {}
-                if isinstance(xla_cost, (list, tuple)):  # jax 0.4.x: one
-                    xla_cost = xla_cost[0] if xla_cost else {}  # dict per exe
                 # XLA's cost_analysis counts while bodies ONCE (scanned
                 # layers / microbatches would be undercounted ~100x); use
                 # the loop-aware HLO cost model instead.

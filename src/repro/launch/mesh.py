@@ -5,54 +5,19 @@ never touches jax device state.  The dry-run entry point sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE importing jax;
 smoke tests and benchmarks see the real (1-device) platform.
 
-jax-version compatibility: newer jax exposes ``jax.sharding.AxisType`` /
-``jax.set_mesh`` and lets ``jax.jit`` resolve bare PartitionSpecs against
-the ambient mesh; jax 0.4.x has neither, but the legacy ``Mesh`` context +
-``pjit`` path is semantically identical.  ``mesh_context`` / ``jit_sharded``
-pick the right spelling so every caller works on both.
+Callers enter a mesh with ``jax.set_mesh(mesh)`` and jit with bare
+PartitionSpec shardings, which jax resolves against that ambient mesh.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: every axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available, else the legacy resource-env
-    context (``Mesh`` is its own context manager on jax 0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def jit_sharded(fn, *, in_shardings, out_shardings, donate_argnums=()):
-    """``jax.jit`` accepting bare PartitionSpec shardings on every jax.
-
-    New jax resolves PartitionSpecs against the ambient mesh set by
-    ``mesh_context``; on jax 0.4.x only ``pjit`` does that, and only inside
-    the legacy mesh context — both are entered the same way by callers.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.jit(fn, in_shardings=in_shardings,
-                       out_shardings=out_shardings,
-                       donate_argnums=donate_argnums)
-    from jax.experimental.pjit import pjit
-
-    return pjit(fn, in_shardings=in_shardings, out_shardings=out_shardings,
-                donate_argnums=donate_argnums)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

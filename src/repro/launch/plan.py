@@ -71,9 +71,7 @@ class CellPlan:
     opt_cfg: Optional[adamw.OptimizerConfig] = None
 
     def lower(self):
-        from repro.launch.mesh import jit_sharded
-
-        jitted = jit_sharded(
+        jitted = jax.jit(
             self.step_fn,
             in_shardings=self.in_specs,
             out_shardings=self.out_specs,

@@ -100,10 +100,10 @@ def quantile_inf_tensor(values: np.ndarray, alpha: float,
         return math.inf
     i = max(0, math.ceil(alpha * n) - 1)
     if backend == "jax":
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64(True):
             return float(jnp.sort(jnp.asarray(values, jnp.float64))[i])
     return float(np.partition(values, i)[i])
 

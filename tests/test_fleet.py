@@ -418,7 +418,15 @@ probs, names = _fleet_problems(4)
 seq = [sched.plan(p) for p in probs]
 res = plan_many(FleetProblem(apps=tuple(probs), names=names), sched)
 ok = bool(res.stats.sharded) and res.stats.devices == 8
-for r, s in zip(res.results, seq):
+# 5 apps bucket to 8, not a multiple of 3 devices: phantom apps pad the
+# app axis to 9 and the program still shards
+probs5, names5 = _fleet_problems(5)
+seq5 = [sched.plan(p) for p in probs5]
+res5 = plan_many(FleetProblem(apps=tuple(probs5), names=names5), sched,
+                 devices=jax.devices()[:3])
+ok = ok and bool(res5.stats.sharded) and res5.stats.devices == 3
+ok = ok and res5.stats.padded_apps == 4
+for r, s in zip(res.results + res5.results, seq + seq5):
     pf, sf = r.plans[0], s.plans[0]
     ok = ok and pf.feasible == sf.feasible and pf.notes == sf.notes
     if pf.feasible:

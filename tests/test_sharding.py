@@ -92,7 +92,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 sys.path.insert(0, {src!r})
-from repro.launch.mesh import jit_sharded, make_mesh_from_shape, mesh_context
+from repro.launch.mesh import make_mesh_from_shape
 from repro.configs.registry import ARCHS
 from repro.models.testing import reduced
 from repro.models.model import cache_schema
@@ -136,8 +136,8 @@ for name in {archs!r}:
             (8, cfg.enc_len, cfg.d_model), jnp.bfloat16)
         batch_specs["enc_embeds"] = P("data")
     step = make_train_step(cfg, opt_cfg, tuning, ctx)
-    with mesh_context(mesh):
-        lowered = jit_sharded(
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(
             step,
             in_shardings=(specs, opt_specs, batch_specs),
             out_shardings=(specs, opt_specs, P()),
@@ -150,7 +150,7 @@ for name in {archs!r}:
         cache_specs = schema_to_pspecs(cs, rules)
         toks = jax.ShapeDtypeStruct((8, 1), jnp.int32)
         serve = make_serve_step(cfg, CellTuning(), ctx)
-        compiled2 = jit_sharded(
+        compiled2 = jax.jit(
             serve,
             in_shardings=(specs, cache_specs, P("data", None)),
             out_shardings=(P("data", "model"), cache_specs),
