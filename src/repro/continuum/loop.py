@@ -56,7 +56,7 @@ from repro.core.scheduler import (
     SchedulerConfig,
 )
 from repro.core.types import Application, Infrastructure
-from repro.obs import Observability, Watchtower
+from repro.obs import Observability, Tracer, Watchtower
 
 from .traces import CarbonTrace, WorkloadTrace
 from .whatif import (
@@ -301,6 +301,24 @@ class ContinuumResult:
                        tracer=tracer)
 
 
+def _plan_spans(tr: Tracer, pid: int, t_plan0: float, t_plan1: float,
+                result) -> None:
+    """The children of a ``plan.evaluate`` span, from the stamps its
+    what-if call carried back; they tile ``[t_plan0, t_plan1]`` in order.
+    Adds none where the call kept no stamps (a degenerate problem)."""
+    ps, t_price = result.plan_stats, result.t_price
+    if ps is None or ps.t_dispatch is None or t_price is None:
+        return
+    tr.add("plan.prepare", t_plan0, ps.t_dispatch, parent=pid)
+    tr.add("plan.dispatch", ps.t_dispatch, ps.t_wait, parent=pid,
+           args=ps.args, h2d_bytes=ps.h2d_bytes)
+    tr.add("plan.wait", ps.t_wait, ps.t_fetch, parent=pid)
+    tr.add("plan.fetch", ps.t_fetch, ps.t_decode, parent=pid,
+           d2h_bytes=ps.d2h_bytes)
+    tr.add("plan.decode", ps.t_decode, t_price, parent=pid)
+    tr.add("plan.price", t_price, t_plan1, parent=pid)
+
+
 def _migration_cells(old: Dict[str, Tuple[str, str]],
                      new: Dict[str, Tuple[str, str]],
                      mig_fee: float, restart_fee: float
@@ -336,9 +354,14 @@ class ContinuumRuntime:
         GreenScheduler(SchedulerConfig(emission_weight=1.0))))
     # Per-run observability bundle (registry + tracer + emissions
     # ledger).  None (the default) keeps both loops at their
-    # uninstrumented cost: the eager tick pays a few perf_counter reads,
-    # the fused scan carries zero extra arrays.
+    # uninstrumented cost: the eager tick pays a dozen perf_counter
+    # reads, the fused scan carries zero extra arrays.
     obs: Optional[Observability] = field(default=None, repr=False)
+    # A tracer on its own: every span of both loops, without the
+    # registry, the ledger or the fused scan's metric lanes (the scan
+    # compiles the same program as with nothing attached).  An attached
+    # bundle's own tracer takes its place.
+    tracer: Optional[Tracer] = field(default=None, repr=False)
     # Green watchtower (repro.obs.watch): streaming anomaly detectors +
     # SLO burn-rate evaluation over each committed tick.  None keeps the
     # loop watch-free; in "observe" mode decisions are bit-identical
@@ -424,6 +447,13 @@ class ContinuumRuntime:
             scheduler=GreenScheduler(dataclasses.replace(
                 sched.config, bucket=spec)))
 
+    def active_tracer(self) -> Optional[Tracer]:
+        """The tracer both loops record spans into: an enabled bundle's,
+        else the lone ``tracer``; None when neither records."""
+        tr = self.obs.tracer if (self.obs is not None and self.obs.enabled) \
+            else self.tracer
+        return tr if tr is not None and tr.enabled else None
+
     def tick(self, t: int) -> TickRecord:
         """One adaptive-loop iteration.  Repoints the pipeline gatherer's
         signal/forecast hooks at the trace's state as of ``t``; ``run``
@@ -433,8 +463,8 @@ class ContinuumRuntime:
         obs = self.obs if (self.obs is not None and self.obs.enabled) \
             else None
         # Stage timestamps are captured unconditionally (a perf_counter
-        # read is ~50 ns); spans materialize from them only when an
-        # Observability bundle is attached.
+        # read is ~50 ns); spans materialize from them only when a tracer
+        # is attached.
         t_tick0 = time.perf_counter()
         # 1. monitoring + carbon ingestion: the gatherer reads the signal
         # as of this tick (window mean -> node.carbon, persistence
@@ -537,7 +567,7 @@ class ContinuumRuntime:
         migration_g = 0.0
         expected_saving = 0.0
         warm_rejected = False
-        plan_stats = None
+        result = None
         t_plan0 = t_plan1 = time.perf_counter()
 
         if replanned:
@@ -573,7 +603,6 @@ class ContinuumRuntime:
             result = self.planner.evaluate(tick_problem)
             t_plan1 = time.perf_counter()
             self.last_result = result
-            plan_stats = result.plan_stats
             cand_plan = result.best_plan
             warm_rejected = any(
                 "warm start rejected" in n for n in cand_plan.notes)
@@ -623,22 +652,10 @@ class ContinuumRuntime:
             constraint_s=constraint_s, dirty_candidates=dirty_candidates,
             evicted=evicted, emergency=emergency,
             violations=len(violations))
+        t_acct1 = time.perf_counter()
         if obs is not None:
-            t_end = time.perf_counter()
-            tr = obs.tracer
-            tid = tr.add("tick", t_tick0, t_end, t=t)
-            tr.add("telemetry.ingest", t_tick0, t_ingest1, parent=tid)
-            tr.add("constraints", t_ingest1, t_cons1, parent=tid,
-                   path=str(cstats.get("path", "")))
-            tr.add("lower.rebuild", t_replan0, t_replan0 + rebuild_s,
-                   parent=tid, path=lowering_path)
-            if replanned:
-                tr.add("plan.evaluate", t_plan0, t_plan1, parent=tid)
-                tr.add("switch", t_plan1, t_acct0, parent=tid,
-                       switched=switched)
-            tr.add("account", t_acct0, t_end, parent=tid)
-            self._record_tick_metrics(obs, rec, t_end - t_tick0,
-                                      plan_stats)
+            self._record_tick_metrics(obs, rec, t_acct1 - t_tick0,
+                                      result, t_plan0, t_plan1)
             if faults is not None:
                 self._record_fault_events(obs, t, evicted, emergency,
                                           violations)
@@ -665,6 +682,23 @@ class ContinuumRuntime:
                 alive=fault_alive, dark_zones=dark,
                 telemetry_stale=stale, node_zones=self._node_regions,
                 registry=obs.registry if obs is not None else None)
+        tr = self.active_tracer()
+        if tr is not None:
+            # the root closes last, so it covers the bundle's bookkeeping
+            # and the watchtower: what the caller pays for the tick
+            tid = tr.add("tick", t_tick0, time.perf_counter(), t=t)
+            tr.add("telemetry.ingest", t_tick0, t_ingest1, parent=tid)
+            tr.add("constraints", t_ingest1, t_cons1, parent=tid,
+                   path=str(cstats.get("path", "")))
+            tr.add("lower.rebuild", t_replan0, t_replan0 + rebuild_s,
+                   parent=tid, path=lowering_path)
+            tr.add("scenarios", t_replan0 + rebuild_s, t_plan0, parent=tid)
+            if replanned:
+                pid = tr.add("plan.evaluate", t_plan0, t_plan1, parent=tid)
+                _plan_spans(tr, pid, t_plan0, t_plan1, result)
+                tr.add("switch", t_plan1, t_acct0, parent=tid,
+                       switched=switched)
+            tr.add("account", t_acct0, t_acct1, parent=tid)
         return rec
 
     def _held_output(self, out, t: int):
@@ -706,8 +740,11 @@ class ContinuumRuntime:
             reg.inc("fault.invariant_violations")
 
     def _record_tick_metrics(self, obs: Observability, rec: TickRecord,
-                             tick_s: float, plan_stats) -> None:
-        """Mirror one TickRecord onto the attached registry."""
+                             tick_s: float, result, t_plan0: float,
+                             t_plan1: float) -> None:
+        """Mirror one TickRecord, and the split of its what-if call
+        (``result``, None when the tick did not replan), onto the attached
+        registry."""
         reg = obs.registry
         reg.inc("runtime.ticks")
         if rec.replanned:
@@ -730,6 +767,11 @@ class ContinuumRuntime:
         reg.observe("stage.replan_s", rec.replan_s)
         reg.observe("stage.tick_s", tick_s)
         reg.observe("tick.emissions_g", rec.emissions_g)
+        plan_stats = result.plan_stats if result is not None else None
+        if result is not None and result.t_price is not None:
+            # the batched plan call, then the cross-ensemble re-pricing
+            reg.observe("stage.plan_s", result.t_price - t_plan0)
+            reg.observe("stage.price_s", t_plan1 - result.t_price)
         if plan_stats is not None:
             labels = plan_stats.metric_labels()
             m = plan_stats.to_metrics()

@@ -244,12 +244,16 @@ def _stage(runtime, start: int, T: int) -> _Staged:
     comps: List[dict] = []
     commus: List[dict] = []
     infras: List[object] = []
+    # per tick, perf_counter stamps where each phase starts (ingest,
+    # engine, lowering, KB, forecast) and where the tick ends
+    stamps: List[Tuple[float, ...]] = []
 
     for k in range(T):
         t = start + k
         it = iter0 + k + 1
 
         # -- tick ingestion: identical hook/profile sequence to tick() --
+        t_ingest = time.perf_counter()
         gatherer.signal = carbon.history_signal(t)
         gatherer.forecast = carbon.forecast_signal(t, cfg.horizon_h)
         mon = workload.monitoring(t)
@@ -277,6 +281,7 @@ def _stage(runtime, start: int, T: int) -> _Staged:
             commu_low = estimator.communication_profiles(monf)
 
         # -- constraint engine: refresh + survivors on the staged cache --
+        t_engine = time.perf_counter()
         skey = eng._structural_key(app_e, infra_e, commu)
         if k == 0:
             live = eng._cache
@@ -343,6 +348,7 @@ def _stage(runtime, start: int, T: int) -> _Staged:
                       scache.cmax, scache.mean_ci, scache.evals))
 
         # -- lowering tiers against a LOCAL cache mirror -----------------
+        t_lower = time.perf_counter()
         out = GeneratorOutput(constraints=(), app=app_low, infra=infra_e,
                               computation=comp_low, communication=commu_low)
         key = ("auto", PlacementProblem.cache_key(out))
@@ -426,6 +432,7 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         order_t.append(np.asarray(low.order, np.int64))
 
         # -- KB columnar simulation + ranking + penalty staging ----------
+        t_kb = time.perf_counter()
         if use_kb:
             fr = np.zeros(st.U, bool)
             fr[fresh_cells] = True
@@ -487,6 +494,7 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         a_val_t.append(a_v)
 
         # -- forecast ensemble + true-CI tensors -------------------------
+        t_forecast = time.perf_counter()
         if cfg.oracle:
             ci_b = carbon.future_matrix(node_regions, t, cfg.horizon_h)
         else:
@@ -505,7 +513,10 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         # eager tick's mask_unavailable(avail_cap := -1) achieves
         alive_t.append(np.asarray(faults.alive_at(t), bool)
                        if faults is not None else np.ones(low.N, bool))
+        stamps.append((t_ingest, t_engine, t_lower, t_kb, t_forecast,
+                       time.perf_counter()))
 
+    st.stamps = stamps
     st.scache, st.snaps, st.ts_store = scache, snaps, ts_store
     st.lows, st.lcache = lows, lcache
     st.paths, st.path_counts = paths, path_counts
@@ -751,7 +762,9 @@ def _scan_fn(kind: str, with_metrics: bool = False,
         single, in_axes=(0, 0, None, None) + (None,) * (5 + comm_argc + 14))
     i64, f64 = jnp.int64, jnp.float64
 
-    def fused(carry0, xs, consts, wconsts):
+    # the function's name is the program's: a device trace names the
+    # module ``jit_fused_tick_scan``
+    def fused_tick_scan(carry0, xs, consts, wconsts):
         (stat_feas, cpu_req, ram_req, cpu_cap, ram_cap, must, cost,
          comm_static, money_w, pref_w, emission_w, green_pen, hyst_eff,
          horizon_h, migration_g, restart_g, max_steps, warm_en,
@@ -805,73 +818,78 @@ def _scan_fn(kind: str, with_metrics: bool = False,
                 # warm start: re-validate the incumbent against this
                 # tick's masks/capacities (all-or-nothing, like
                 # _warm_start_state's reject-and-rebuild)
-                feas_w = jnp.where(
-                    placed_c, stat_feas_t[s_ix, fcur_c, ncur_c], True).all()
-                cpu_l = jnp.zeros(N, f64).at[ncur_c].add(
-                    jnp.where(placed_c, cpu_req[s_ix, fcur_c], 0.0))
-                ram_l = jnp.zeros(N, f64).at[ncur_c].add(
-                    jnp.where(placed_c, ram_req[s_ix, fcur_c], 0.0))
-                ok = (has_c & warm_en & feas_w
-                      & (cpu_l <= cpu_cap).all()
-                      & (ram_l <= ram_cap).all())
-                warm_rej = has_c & warm_en & ~ok
-                w_placed = placed_c & ok
-                w_f = jnp.where(ok, fcur_c, zi)
-                w_n = jnp.where(ok, ncur_c, zi)
-                w_cpu = jnp.where(ok, cpu_l, zf)
-                w_ram = jnp.where(ok, ram_l, zf)
-                P = jnp.zeros(S * F * N, f64).at[p_idx].add(
-                    p_val).reshape(S, F, N)
-                A = jnp.zeros(S * S, f64).at[a_idx].add(
-                    a_val).reshape(S, S)
-                placed_b, fcur_b, ncur_b, _, infeas_b, _ = vplan(
-                    ci_b, ci_mean_b, E, order, w_placed, w_f, w_n,
-                    w_cpu, w_ram, *comm_args, P, A, stat_feas_t, cpu_req,
-                    ram_req, cpu_cap, ram_cap, must, cost, money_w,
-                    pref_w, emission_w, green_pen, max_steps)
-                em = expected_of(placed_b, fcur_b, ncur_b)     # [B, B]
-                em = jnp.where(infeas_b[:, None], jnp.inf, em)
-                expected = em.mean(axis=1)
-                best = jnp.argmin(expected)
-                feasible = ~infeas_b[best]
-                cand_p = placed_b[best]
-                cand_f = fcur_b[best]
-                cand_n = ncur_b[best]
-                cur_em = expected_of(
-                    placed_c[None], fcur_c[None], ncur_c[None])
-                cur_expected = cur_em.mean()
-                both = cand_p & placed_c
-                same = ((cand_p == placed_c)
-                        & (~both | ((cand_f == fcur_c)
-                                    & (cand_n == ncur_c)))).all()
-                moved = ((cand_p & (~placed_c | (cand_n != ncur_c)))
-                         .sum(dtype=i64)
-                         + (placed_c & ~cand_p).sum(dtype=i64))
-                flapped = (both & (cand_n == ncur_c)
-                           & (cand_f != fcur_c)).sum(dtype=i64)
-                cost_sw = migration_g * moved + restart_g * flapped
-                saving = (cur_expected - expected[best]) * horizon_h
-                adopt = feasible & ~has_c
-                consider = feasible & has_c & ~same
-                # emergency = the eager gate's force flag: evacuating a
-                # dead node must never lose to flap damping, but the
-                # migration/restart fees are still counted and billed
-                do_switch = consider & ((saving > cost_sw + hyst_eff)
-                                        | emergency)
-                take = adopt | do_switch
-                new_p = jnp.where(take, cand_p, placed_c)
-                new_f = jnp.where(take, jnp.where(cand_p, cand_f, zi),
-                                  fcur_c)
-                new_n = jnp.where(take, jnp.where(cand_p, cand_n, zi),
-                                  ncur_c)
-                new_has = has_c | adopt
-                migs = jnp.where(adopt, cand_p.sum(dtype=i64),
-                                 jnp.where(do_switch, moved, zi))
-                rsts = jnp.where(do_switch, flapped, zi)
-                mgc = jnp.where(do_switch, cost_sw, zf)
-                sav = jnp.where(consider, saving, zf)
-                return ((new_p, new_f, new_n, new_has),
-                        (take, migs, rsts, mgc, sav, warm_rej))
+                with jax.named_scope("warm_start"):
+                    feas_w = jnp.where(
+                        placed_c, stat_feas_t[s_ix, fcur_c, ncur_c],
+                        True).all()
+                    cpu_l = jnp.zeros(N, f64).at[ncur_c].add(
+                        jnp.where(placed_c, cpu_req[s_ix, fcur_c], 0.0))
+                    ram_l = jnp.zeros(N, f64).at[ncur_c].add(
+                        jnp.where(placed_c, ram_req[s_ix, fcur_c], 0.0))
+                    ok = (has_c & warm_en & feas_w
+                          & (cpu_l <= cpu_cap).all()
+                          & (ram_l <= ram_cap).all())
+                    warm_rej = has_c & warm_en & ~ok
+                    w_placed = placed_c & ok
+                    w_f = jnp.where(ok, fcur_c, zi)
+                    w_n = jnp.where(ok, ncur_c, zi)
+                    w_cpu = jnp.where(ok, cpu_l, zf)
+                    w_ram = jnp.where(ok, ram_l, zf)
+                with jax.named_scope("plan"):
+                    P = jnp.zeros(S * F * N, f64).at[p_idx].add(
+                        p_val).reshape(S, F, N)
+                    A = jnp.zeros(S * S, f64).at[a_idx].add(
+                        a_val).reshape(S, S)
+                    placed_b, fcur_b, ncur_b, _, infeas_b, _ = vplan(
+                        ci_b, ci_mean_b, E, order, w_placed, w_f, w_n,
+                        w_cpu, w_ram, *comm_args, P, A, stat_feas_t,
+                        cpu_req, ram_req, cpu_cap, ram_cap, must, cost,
+                        money_w, pref_w, emission_w, green_pen, max_steps)
+                with jax.named_scope("price"):
+                    em = expected_of(placed_b, fcur_b, ncur_b)  # [B, B]
+                    em = jnp.where(infeas_b[:, None], jnp.inf, em)
+                    expected = em.mean(axis=1)
+                    best = jnp.argmin(expected)
+                    cur_em = expected_of(
+                        placed_c[None], fcur_c[None], ncur_c[None])
+                    cur_expected = cur_em.mean()
+                with jax.named_scope("switch"):
+                    feasible = ~infeas_b[best]
+                    cand_p = placed_b[best]
+                    cand_f = fcur_b[best]
+                    cand_n = ncur_b[best]
+                    both = cand_p & placed_c
+                    same = ((cand_p == placed_c)
+                            & (~both | ((cand_f == fcur_c)
+                                        & (cand_n == ncur_c)))).all()
+                    moved = ((cand_p & (~placed_c | (cand_n != ncur_c)))
+                             .sum(dtype=i64)
+                             + (placed_c & ~cand_p).sum(dtype=i64))
+                    flapped = (both & (cand_n == ncur_c)
+                               & (cand_f != fcur_c)).sum(dtype=i64)
+                    cost_sw = migration_g * moved + restart_g * flapped
+                    saving = (cur_expected - expected[best]) * horizon_h
+                    adopt = feasible & ~has_c
+                    consider = feasible & has_c & ~same
+                    # emergency = the eager gate's force flag: evacuating a
+                    # dead node must never lose to flap damping, but the
+                    # migration/restart fees are still counted and billed
+                    do_switch = consider & ((saving > cost_sw + hyst_eff)
+                                            | emergency)
+                    take = adopt | do_switch
+                    new_p = jnp.where(take, cand_p, placed_c)
+                    new_f = jnp.where(take, jnp.where(cand_p, cand_f, zi),
+                                      fcur_c)
+                    new_n = jnp.where(take, jnp.where(cand_p, cand_n, zi),
+                                      ncur_c)
+                    new_has = has_c | adopt
+                    migs = jnp.where(adopt, cand_p.sum(dtype=i64),
+                                     jnp.where(do_switch, moved, zi))
+                    rsts = jnp.where(do_switch, flapped, zi)
+                    mgc = jnp.where(do_switch, cost_sw, zf)
+                    sav = jnp.where(consider, saving, zf)
+                    return ((new_p, new_f, new_n, new_has),
+                            (take, migs, rsts, mgc, sav, warm_rej))
 
             def skip_branch(carry):
                 return (carry, (jnp.asarray(False), zi, zi, zf, zf,
@@ -896,10 +914,11 @@ def _scan_fn(kind: str, with_metrics: bool = False,
             # (mirrors lowered_emissions; the commit recomputes this on
             # host as the authoritative record, the in-jit value feeds
             # whole-trace what-ifs like monte_carlo_emissions)
-            comp_n = (placed2 * E[s_ix, f2] * ci_now[n2]).sum()
-            commE_n = pair_many(placed2[None], f2[None], n2[None])[0]
-            em_tick = jnp.where(has2 & placed2.any(),
-                                comp_n + commE_n * ci_now.mean(), zf)
+            with jax.named_scope("account"):
+                comp_n = (placed2 * E[s_ix, f2] * ci_now[n2]).sum()
+                commE_n = pair_many(placed2[None], f2[None], n2[None])[0]
+                em_tick = jnp.where(has2 & placed2.any(),
+                                    comp_n + commE_n * ci_now.mean(), zf)
             ys = (do_plan, wrj, switched, migs, rsts, mgc, sav,
                   placed2, f2, n2, has2, em_tick, n_evicted, emergency)
             out_carry = carry2
@@ -954,7 +973,7 @@ def _scan_fn(kind: str, with_metrics: bool = False,
 
         return lax.scan(step, carry0, xs)
 
-    fn = jax.jit(fused)
+    fn = jax.jit(fused_tick_scan)
     _SCAN_CACHE[(kind, with_metrics, with_watch)] = fn
     return fn
 
@@ -1316,6 +1335,7 @@ def run_scanned(runtime, start: int, ticks: int):
     runtime.last_scanned_fallback = None
     obs = runtime.obs if (getattr(runtime, "obs", None) is not None
                           and runtime.obs.enabled) else None
+    tr = runtime.active_tracer()
     if ticks <= 0:
         return ContinuumResult(
             ticks=[], final_assignment=dict(runtime.current or {}))
@@ -1366,6 +1386,7 @@ def run_scanned(runtime, start: int, ticks: int):
     t1 = time.perf_counter()
     with jax.enable_x64(True):
         carry_out, ys = fn(carry0, st.xs, st.consts, wconsts)
+        t_wait = time.perf_counter()
         ys = jax.block_until_ready(ys)
     scan_s = time.perf_counter() - t1
     wys = tuple(np.asarray(w) for w in ys[-1]) if with_watch else None
@@ -1375,6 +1396,7 @@ def run_scanned(runtime, start: int, ticks: int):
     carry_out = tuple(
         np.asarray(c) for c in
         carry_out[:5 if with_metrics else 4])
+    t_fetch1 = time.perf_counter()
     result = _commit(runtime, st, carry_out, ys, start, stage_s, scan_s,
                      obs=obs)
     if with_watch:
@@ -1383,13 +1405,27 @@ def run_scanned(runtime, start: int, ticks: int):
         # per-tick order as the eager observe_tick
         watch.commit_scan(runtime, st, result.ticks, wys, wcarry,
                           start, obs=obs)
-    if obs is not None:
+    if tr is not None:
         t_end = time.perf_counter()
-        tr = obs.tracer
         tid = tr.add("run_scanned", t0, t_end, ticks=ticks)
-        tr.add("scan.stage", t0, t0 + stage_s, parent=tid)
-        tr.add("scan.fused", t1, t1 + scan_s, parent=tid)
-        tr.add("scan.commit", t1 + scan_s, t_end, parent=tid)
+        sid = tr.add("scan.stage", t0, t0 + stage_s, parent=tid,
+                     replanned=int(np.sum(st.xs[0])), **st.path_counts)
+        for t_in, t_en, t_lo, t_kb, t_fc, t_k1 in st.stamps:
+            tr.add("scan.stage.ingest", t_in, t_en, parent=sid)
+            tr.add("scan.stage.engine", t_en, t_lo, parent=sid)
+            tr.add("scan.stage.lower", t_lo, t_kb, parent=sid)
+            tr.add("scan.stage.engine", t_kb, t_fc, parent=sid)
+            tr.add("scan.stage.ingest", t_fc, t_k1, parent=sid)
+        fid = tr.add("scan.fused", t1, t1 + scan_s, parent=tid)
+        sent = [a for a in jax.tree_util.tree_leaves(
+            (carry0, st.xs, st.consts, wconsts)) if hasattr(a, "nbytes")]
+        tr.add("scan.dispatch", t1, t_wait, parent=fid, args=len(sent),
+               h2d_bytes=int(sum(a.nbytes for a in sent)))
+        tr.add("scan.wait", t_wait, t1 + scan_s, parent=fid)
+        cid = tr.add("scan.commit", t1 + scan_s, t_end, parent=tid)
+        fetched = jax.tree_util.tree_leaves((ys, carry_out, wys, wcarry))
+        tr.add("scan.fetch", t1 + scan_s, t_fetch1, parent=cid,
+               d2h_bytes=int(sum(a.nbytes for a in fetched)))
     return result
 
 

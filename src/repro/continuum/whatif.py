@@ -26,7 +26,6 @@ from repro.core.lowering import LoweredProblem, ScenarioBatch
 from repro.core.problem import PlacementProblem, PlanStats
 from repro.core.scheduler import GreenScheduler, SchedulerConfig
 from repro.core.types import Constraint, DeploymentPlan
-from repro.obs.registry import REGISTRY as _REGISTRY
 
 
 def assignment_arrays(
@@ -65,6 +64,9 @@ class WhatIfResult:
     # compile-cache / timing telemetry of the one batched plan call (None
     # on the sequential reference path, which makes B separate calls)
     plan_stats: Optional[PlanStats] = None
+    # time.perf_counter() when the cross-ensemble pricing of the plans
+    # began, after the plan call returned (None on the sequential path)
+    t_price: Optional[float] = None
 
     @property
     def best_plan(self) -> DeploymentPlan:
@@ -170,16 +172,12 @@ class WhatIfPlanner:
             raise ValueError(
                 "what-if evaluation needs problem.scenarios (a "
                 "ScenarioBatch of forecast branches)")
-        t0 = time.perf_counter()
         result = self.scheduler.plan(problem)
-        t1 = time.perf_counter()
+        t_price = time.perf_counter()
         arrays = [result.arrays(b) for b in range(result.B)]
         scored = _score(problem.lowering, result.plans, problem.scenarios,
-                       arrays=arrays, plan_stats=result.stats)
-        # Stage split for the tick pipeline: the batched plan call vs the
-        # cross-ensemble re-pricing that follows it.
-        _REGISTRY.observe("stage.plan_s", t1 - t0)
-        _REGISTRY.observe("stage.price_s", time.perf_counter() - t1)
+                        arrays=arrays, plan_stats=result.stats)
+        scored.t_price = t_price
         return scored
 
     def evaluate_sequential(

@@ -200,8 +200,6 @@ class GreenConstraintPipeline:
                 "path": "reference",
                 "constraint_s": time.perf_counter() - t0,
             }
-            _REGISTRY.observe("stage.constraint_s",
-                              self.constraint_stats["constraint_s"])
         elif self.engine in ("array", "parity"):
             eng = self._ensure_engine()
             if self.engine == "parity" and self._shadow_kb is None:
@@ -223,8 +221,6 @@ class GreenConstraintPipeline:
                 "reused": s.reused, "fresh": s.fresh,
                 "retrieved": s.retrieved, "constraints": s.constraints,
             }
-            _REGISTRY.observe("stage.constraint_s",
-                              self.constraint_stats["constraint_s"])
             _REGISTRY.inc("engine.dirty_candidates", s.rescored)
             _REGISTRY.gauge("engine.candidates", s.candidates)
             if self.engine == "parity":

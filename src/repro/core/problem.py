@@ -219,6 +219,15 @@ class PlanStats:
     XLA compile).  The cumulative ``cache_hits``/``cache_misses``
     counters snapshot the process-wide planner compile cache after this
     call.
+
+    The ``t_*`` fields are ``time.perf_counter()`` stamps of the call's
+    phases, from which a caller that holds a tracer builds its spans:
+    the program's dispatch starts at ``t_dispatch``, the wait for the
+    device at ``t_wait``, the copies to the host at ``t_fetch`` and the
+    decode of the plans at ``t_decode`` (``plan_time_s`` is ``t_decode -
+    t_dispatch``).  ``args`` and ``h2d_bytes`` count the array arguments
+    handed to the program and their bytes; ``d2h_bytes`` the bytes of
+    its outputs copied back.
     """
 
     backend: str
@@ -231,6 +240,13 @@ class PlanStats:
     plan_time_s: float
     cache_hits: int
     cache_misses: int
+    t_dispatch: Optional[float] = None
+    t_wait: Optional[float] = None
+    t_fetch: Optional[float] = None
+    t_decode: Optional[float] = None
+    args: int = 0
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
     def metric_labels(self) -> Dict[str, str]:
         """Label set for registry metrics derived from this call."""

@@ -277,7 +277,6 @@ class ConstraintEngine:
         )
         self.last_stats = stats
         _REGISTRY.inc("engine.passes", labels={"mode": stats.mode})
-        _REGISTRY.observe("engine.pass_s", stats.elapsed_s)
         return EngineResult(constraints=constraints, stats=stats)
 
     def run_from_monitoring(self, app, infra, monitoring, iteration,

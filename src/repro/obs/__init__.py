@@ -5,13 +5,15 @@ Two tiers:
 
 * the process-global :data:`REGISTRY` collects cheap wiring counters
   (planner compile cache, lowering tiers, constraint-engine dirty
-  accounting) unconditionally — read it with :func:`metrics_scope` to
-  get bleed-free deltas;
+  accounting) unconditionally, and no histograms — read it with
+  :func:`metrics_scope` to get bleed-free deltas;
 * an :class:`Observability` bundle, explicitly attached to a
   ``ContinuumRuntime`` (``obs=Observability()``), turns on per-run
-  spans, per-tick metrics, and the emissions ledger.  Detached (the
-  default), the runtime pays nothing beyond a few ``perf_counter``
-  reads per tick, and the fused scan carries zero extra arrays.
+  spans, per-tick metrics, and the emissions ledger.  A lone
+  :class:`Tracer` (``tracer=Tracer()``) turns on the spans alone.
+  Detached (the default), the runtime pays nothing beyond a dozen
+  ``perf_counter`` reads per tick, and the fused scan carries zero
+  extra arrays.
 
 Quickstart::
 

@@ -1,16 +1,43 @@
 """Span tracer for the per-tick pipeline.
 
 Spans are half-open ``[t0, t1)`` wall-clock intervals with an optional
-parent, forming one tree per tick:
+parent, forming one tree per eager tick:
 
     tick
     ├── telemetry.ingest
     ├── constraints
     ├── lower.rebuild
+    ├── scenarios            fault masks, forecast ensemble, warm start
     ├── plan.evaluate        (only on replanned ticks)
-    │   └── (whatif plan/price timings live in the registry)
+    │   ├── plan.prepare     warm-start check, materialize, padding
+    │   ├── plan.dispatch    the jitted planner call   (args, h2d_bytes)
+    │   ├── plan.wait        block_until_ready on its outputs
+    │   ├── plan.fetch       copies to the host        (d2h_bytes)
+    │   ├── plan.decode      plan objects
+    │   └── plan.price       cross-ensemble pricing
     ├── switch
     └── account
+
+and one per fused replay (``run_scanned``):
+
+    run_scanned
+    ├── scan.stage           (replanned, cache_hit, delta, full)
+    │   ├── scan.stage.ingest   per staged tick, signals and profiles
+    │   ├── scan.stage.engine   per staged tick, constraint pass and KB
+    │   └── scan.stage.lower    per staged tick, lowering
+    ├── scan.fused
+    │   ├── scan.dispatch    the jitted scan call      (args, h2d_bytes)
+    │   └── scan.wait        block_until_ready on its outputs
+    └── scan.commit
+        └── scan.fetch       copies to the host        (d2h_bytes)
+
+The children of ``plan.evaluate`` and of ``scan.fused`` tile their
+parent in order.  The runtime takes its stamps with
+``time.perf_counter()`` whether or not a tracer is attached; code below
+it (the planner, the what-if pricing) hands its stamps back on its
+results, and the runtime builds spans from them only when a tracer is
+attached (``ContinuumRuntime(tracer=...)``, or an ``Observability``
+bundle's).
 
 Two ways to record:
 

@@ -237,7 +237,9 @@ def test_scanned_ledger_bit_parity_matches_eager():
         sum(r.emissions_g for r in res_s.ticks))
     assert reg.value("runtime.migrations") == \
         sum(r.migrations for r in res_s.ticks)
-    names = [s.name for s in rt_s.obs.tracer.spans]
+    tr = rt_s.obs.tracer
+    (root,) = tr.by_name("run_scanned")
+    names = [root.name] + [s.name for s in tr.children(root.span_id)]
     assert names == ["run_scanned", "scan.stage", "scan.fused",
                      "scan.commit"]
 
