@@ -314,7 +314,7 @@ def _plan_spans(tr: Tracer, pid: int, t_plan0: float, t_plan1: float,
            args=ps.args, h2d_bytes=ps.h2d_bytes)
     tr.add("plan.wait", ps.t_wait, ps.t_fetch, parent=pid)
     tr.add("plan.fetch", ps.t_fetch, ps.t_decode, parent=pid,
-           d2h_bytes=ps.d2h_bytes)
+           outs=ps.outs, d2h_bytes=ps.d2h_bytes)
     tr.add("plan.decode", ps.t_decode, t_price, parent=pid)
     tr.add("plan.price", t_price, t_plan1, parent=pid)
 

@@ -225,9 +225,11 @@ class PlanStats:
     the program's dispatch starts at ``t_dispatch``, the wait for the
     device at ``t_wait``, the copies to the host at ``t_fetch`` and the
     decode of the plans at ``t_decode`` (``plan_time_s`` is ``t_decode -
-    t_dispatch``).  ``args`` and ``h2d_bytes`` count the array arguments
-    handed to the program and their bytes; ``d2h_bytes`` the bytes of
-    its outputs copied back.
+    t_dispatch``).  ``args`` and ``h2d_bytes`` count the packed buffers
+    handed to the program (float64, int64 and bool: every argument of
+    the planner, scalars included, lies in one of them) and their bytes;
+    ``outs`` and ``d2h_bytes`` count the device arrays copied back (one
+    packed int32 array) and their bytes.
     """
 
     backend: str
@@ -246,6 +248,7 @@ class PlanStats:
     t_decode: Optional[float] = None
     args: int = 0
     h2d_bytes: int = 0
+    outs: int = 0
     d2h_bytes: int = 0
 
     def metric_labels(self) -> Dict[str, str]:

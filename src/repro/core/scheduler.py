@@ -44,6 +44,7 @@ Three standard profiles:
 """
 from __future__ import annotations
 
+import math
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -203,7 +204,7 @@ def _move_deltas(xp, static, W, stat_feas, cpu_req, ram_req, cpu_cap,
                                ncur, cpu_load, ram_load)
 
 
-_PLAN_BATCH_CACHE: Dict[str, object] = {}
+_PLAN_BATCH_CACHE: Dict[Tuple, object] = {}
 _PLAN_SINGLE_CACHE: Dict[str, object] = {}
 
 PLANNER_COMM_ARGC = {"dense": 2, "sparse": 4}
@@ -369,31 +370,119 @@ def planner_single(kind: str):
     return single
 
 
-def _batched_planner(kind: str):
+# The planner's three packed argument buffers, in call order.
+_F64, _I64, _BOOL = 0, 1, 2
+_PLAN_BUFFER_DTYPES = (np.float64, np.int64, np.bool_)
+
+
+def _plan_layout(sig: Tuple) -> Tuple[Tuple, Tuple[int, int, int]]:
+    """Where each argument of :func:`planner_single` lies in the packed
+    buffers of one planner call.
+
+    Returns ``(slots, sizes)``: one ``(buffer, offset, shape)`` per
+    argument in signature order, ``buffer`` indexing ``(float64, int64,
+    bool)``, and each buffer's length.  A function of the padded
+    signature ``(kind, B, S, F, N, L)`` alone, so every call with one
+    compile key shares one layout and one program.
+    """
+    kind, B, S, F, N, L = sig
+    comm = (((_F64, (S, F, S)), (_BOOL, (S, F, S)))     # K, has_link
+            if kind == "dense"
+            else ((_I64, (L,)),) * 3 + ((_F64, (L,)),))  # src, fidx, dst, k
+    fields = (
+        (_F64, (B, N)), (_F64, (B,)), (_F64, (B, S, F)),  # ci, ci_mean, E
+        (_I64, (B, S)),                                    # order
+        (_BOOL, (S,)), (_I64, (S,)), (_I64, (S,)),        # warm start
+        (_F64, (N,)), (_F64, (N,)),
+        *comm,
+        (_F64, (S, F, N)), (_F64, (S, S)), (_BOOL, (S, F, N)),  # P, A, mask
+        (_F64, (S, F)), (_F64, (S, F)), (_F64, (N,)), (_F64, (N,)),
+        (_BOOL, (S,)), (_F64, (N,)),                       # must, cost
+        *((_F64, ()),) * 4,                                # the weights
+        (_I64, ()))                                        # max_steps
+    sizes = [0, 0, 0]
+    slots = []
+    for buf, shape in fields:
+        slots.append((buf, sizes[buf], shape))
+        sizes[buf] += math.prod(shape)
+    return tuple(slots), tuple(sizes)
+
+
+def _pack_plan_args(sig: Tuple, args: Sequence) -> List[np.ndarray]:
+    """Copy the planner's arguments into its three packed buffers.
+
+    Values keep their dtype class: floats stay float64, integers stay
+    exact int64, booleans stay bool (a float into an integer buffer
+    raises)."""
+    slots, sizes = _plan_layout(sig)
+    bufs = [np.empty(n, dt) for n, dt in zip(sizes, _PLAN_BUFFER_DTYPES)]
+    for a, (buf, off, shape) in zip(args, slots, strict=True):
+        a = np.asarray(a)
+        if a.shape != shape:
+            raise ValueError(
+                f"planner argument of shape {a.shape}, layout {shape}")
+        np.copyto(bufs[buf][off:off + a.size], a.reshape(-1),
+                  casting="same_kind")
+    return bufs
+
+
+def _unpack_plan_out(out: np.ndarray, B: int, S: int, S_p: int,
+                     fail_dtype) -> Tuple[np.ndarray, ...]:
+    """Split the planner's packed ``[B_p, 4 S_p + 2]`` int32 output into
+    ``(placed, fcur, ncur, skipped, infeas, fail_s)`` for the first ``B``
+    branches and ``S`` services, in the dtypes :func:`planner_single`
+    returns them (``fail_s`` in the order's dtype)."""
+    out = out[:B]
+    return (out[:, :S] != 0,
+            out[:, S_p:S_p + S].astype(np.int64),
+            out[:, 2 * S_p:2 * S_p + S].astype(np.int64),
+            out[:, 3 * S_p:3 * S_p + S] != 0,
+            out[:, 4 * S_p] != 0,
+            out[:, 4 * S_p + 1].astype(fail_dtype))
+
+
+def _batched_planner(sig: Tuple):
     """One jit-compiled program planning B scenario branches at once.
 
-    Built lazily (jax import deferred) and cached per communication-storage
-    ``kind`` so every adaptive-loop tick with unchanged problem shapes
-    reuses the compiled executable — the problem tensors are ARGUMENTS,
-    not closed-over constants, so drifting profiles/forecasts never
-    retrace.  The vmapped body is exactly :func:`planner_single`.
-    """
-    if kind in _PLAN_BATCH_CACHE:
-        return _PLAN_BATCH_CACHE[kind]
-    import jax
+    Built lazily (jax import deferred) and cached per padded signature
+    ``sig = (kind, B, S, F, N, L)`` so every adaptive-loop tick with
+    unchanged problem shapes reuses the compiled executable — the problem
+    tensors are ARGUMENTS, not closed-over constants, so drifting
+    profiles/forecasts never retrace.  The vmapped body is exactly
+    :func:`planner_single`.
 
-    comm_argc = PLANNER_COMM_ARGC[kind]
+    The program takes the arguments as three packed buffers (float64,
+    int64, bool; :func:`_plan_layout`), unpacked with static slices, and
+    returns one ``[B, 4 S + 2]`` int32 array: ``placed``, ``fcur``,
+    ``ncur`` and ``skipped`` as blocks of ``S`` columns, then ``infeas``
+    and ``fail_s`` (:func:`_unpack_plan_out`).  Each array that crosses
+    the host-device boundary costs a fixed latency whatever its size, so
+    one call moves three arrays in and one out.
+    """
+    if sig in _PLAN_BATCH_CACHE:
+        return _PLAN_BATCH_CACHE[sig]
+    import jax
+    import jax.numpy as jnp
+
+    comm_argc = PLANNER_COMM_ARGC[sig[0]]
     batched = jax.vmap(
-        planner_single(kind),
+        planner_single(sig[0]),
         in_axes=(0, 0, 0, 0) + (None,) * (5 + comm_argc + 14))
+    slots, _ = _plan_layout(sig)
 
     # the function's name is the program's: a device trace names the
     # module ``jit_green_planner``
-    def green_planner(*args):
-        return batched(*args)
+    def green_planner(f64, i64, flags):
+        bufs = (f64, i64, flags)
+        args = [bufs[b][off:off + math.prod(shape)].reshape(shape)
+                for b, off, shape in slots]
+        placed, fcur, ncur, skipped, infeas, fail_s = batched(*args)
+        return jnp.concatenate(
+            [placed, fcur, ncur, skipped, infeas[:, None], fail_s[:, None]],
+            axis=1, dtype=jnp.int32)
 
     fn = jax.jit(green_planner)
-    _PLAN_BATCH_CACHE[kind] = fn
+    _PLAN_BATCH_CACHE[sig] = fn
     return fn
 
 
@@ -705,47 +794,43 @@ class GreenScheduler:
 
         import jax
 
-        planner = _batched_planner(plow.comm.kind)
         sig = (plow.comm.kind,) + padded_shape
+        planner = _batched_planner(sig)
         # x64 keeps branch plans bit-comparable across batch sizes and
         # backends: a float32 downcast would drown the _EPS improvement
         # threshold in rounding noise and let the local search ping-pong
         # on near-ties.
-        args = (ci_b, ci_mean_b, E_b, order_b, *warm,
-                *plow.comm.planner_args(), P, A, stat_feas,
-                plow.cpu_req, plow.ram_req, plow.cpu_cap, plow.ram_cap,
-                plow.must, plow.cost,
-                cfg.money_weight, cfg.pref_weight, cfg.emission_weight,
-                cfg.green_penalty,
-                cfg.local_search_rounds * max(1, S))
+        bufs = _pack_plan_args(sig, (
+            ci_b, ci_mean_b, E_b, order_b, *warm,
+            *plow.comm.planner_args(), P, A, stat_feas,
+            plow.cpu_req, plow.ram_req, plow.cpu_cap, plow.ram_cap,
+            plow.must, plow.cost,
+            cfg.money_weight, cfg.pref_weight, cfg.emission_weight,
+            cfg.green_penalty,
+            cfg.local_search_rounds * max(1, S)))
         t_dispatch = time.perf_counter()
         with jax.enable_x64(True):
-            out = planner(*args)
+            out = planner(*bufs)
         t_wait = time.perf_counter()
         out = jax.block_until_ready(out)
         t_fetch = time.perf_counter()
-        out = [np.asarray(a) for a in out]
+        out = np.asarray(out)
         t_decode = time.perf_counter()
-        placed_b, fcur_b, ncur_b, skipped_b, infeas_b, fail_b = (
-            a[:B, ...] for a in out)
+        # phantom branches and services sliced away
+        placed_b, fcur_b, ncur_b, skipped_b, infeas_b, fail_b = \
+            _unpack_plan_out(out, B, S, plow.S, order_b.dtype)
         plan_time_s = t_decode - t_dispatch
         compiled = COMPILE_CACHE.record(sig, plan_time_s)
         cc = COMPILE_CACHE
-        arrays = [a for a in args if isinstance(a, np.ndarray)]
         stats = PlanStats(
             backend=plow.comm.kind, shape=shape, padded_shape=padded_shape,
             signature=sig, bucketed=bucketed, compiled=compiled,
             compile_time_s=plan_time_s if compiled else 0.0,
             plan_time_s=plan_time_s, cache_hits=cc.hits,
             cache_misses=cc.misses, t_dispatch=t_dispatch, t_wait=t_wait,
-            t_fetch=t_fetch, t_decode=t_decode, args=len(arrays),
-            h2d_bytes=sum(a.nbytes for a in arrays),
-            d2h_bytes=sum(a.nbytes for a in out))
-        # slice phantom services away; phantom branches already dropped
-        placed_b = placed_b[:, :S]
-        fcur_b = fcur_b[:, :S]
-        ncur_b = ncur_b[:, :S]
-        skipped_b = skipped_b[:, :S]
+            t_fetch=t_fetch, t_decode=t_decode, args=len(bufs),
+            h2d_bytes=sum(b.nbytes for b in bufs), outs=1,
+            d2h_bytes=out.nbytes)
         ci_b = ci_b[:B, :N]
         E_b = E_b[:B, :S, :F]
         order_b = order_b[:B, :S]

@@ -11,8 +11,8 @@ parent, forming one tree per eager tick:
     ├── plan.evaluate        (only on replanned ticks)
     │   ├── plan.prepare     warm-start check, materialize, padding
     │   ├── plan.dispatch    the jitted planner call   (args, h2d_bytes)
-    │   ├── plan.wait        block_until_ready on its outputs
-    │   ├── plan.fetch       copies to the host        (d2h_bytes)
+    │   ├── plan.wait        block_until_ready on its output
+    │   ├── plan.fetch       copy to the host          (outs, d2h_bytes)
     │   ├── plan.decode      plan objects
     │   └── plan.price       cross-ensemble pricing
     ├── switch
@@ -32,7 +32,10 @@ and one per fused replay (``run_scanned``):
         └── scan.fetch       copies to the host        (d2h_bytes)
 
 The children of ``plan.evaluate`` and of ``scan.fused`` tile their
-parent in order.  The runtime takes its stamps with
+parent in order.  The planner call sends its arguments as three packed
+buffers and returns one packed array, so ``plan.dispatch`` counts
+``args`` == 3 and ``plan.fetch`` ``outs`` == 1; ``args``/``outs`` count
+arrays and ``*_bytes`` their bytes.  The runtime takes its stamps with
 ``time.perf_counter()`` whether or not a tracer is attached; code below
 it (the planner, the what-if pricing) hands its stamps back on its
 results, and the runtime builds spans from them only when a tracer is

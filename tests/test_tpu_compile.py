@@ -75,11 +75,23 @@ def _spec(arg, sharding):
                                          ("sparse", 120, 20)])
 def test_planner_compiles_for_v5e(monkeypatch, one_chip, backend, S, N):
     problem = PlacementProblem.build(*synth(S, N), backend=backend)
-    program, calls = _spy(
-        monkeypatch, scheduler._PLAN_BATCH_CACHE, backend,
-        lambda: scheduler._batched_planner(backend))
+    build, programs, calls = scheduler._batched_planner, [], []
+
+    def spy(sig):
+        program = build(sig)
+        programs.append(program)
+
+        def call(*args):
+            calls.append(args)
+            return program(*args)
+        return call
+
+    monkeypatch.setattr(scheduler, "_batched_planner", spy)
     GreenScheduler(SchedulerConfig.green()).plan(problem)
-    (args,) = calls
+    (program,), (args,) = programs, calls
+    # the arguments travel as at most three packed arrays
+    assert len(args) <= 3
+    assert all(isinstance(a, np.ndarray) for a in args)
     with jax.enable_x64(True):
         compiled = program.lower(
             *(_spec(a, one_chip) for a in args)).compile()

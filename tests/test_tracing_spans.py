@@ -180,8 +180,8 @@ def test_transfer_counts_are_the_bytes_of_the_calls(monkeypatch):
     calls = []
     batched = scheduler._batched_planner
 
-    def spy_planner(kind):
-        fn = batched(kind)
+    def spy_planner(sig):
+        fn = batched(sig)
 
         def call(*args):
             out = fn(*args)
@@ -210,11 +210,15 @@ def test_transfer_counts_are_the_bytes_of_the_calls(monkeypatch):
     assert len(plans) == 2 and len(tr.by_name("plan.dispatch")) == 2
     for (_, args, out), disp, fetch in zip(
             plans, tr.by_name("plan.dispatch"), tr.by_name("plan.fetch")):
+        # every argument is an array, and there are at most three of them
         sent = [a for a in args if isinstance(a, np.ndarray)]
+        assert len(sent) == len(args) <= 3
         assert disp.attrs["args"] == len(sent)
         assert disp.attrs["h2d_bytes"] == sum(a.nbytes for a in sent) > 0
+        fetched = jax.tree_util.tree_leaves(out)
+        assert fetch.attrs["outs"] == len(fetched) == 1
         assert fetch.attrs["d2h_bytes"] == sum(
-            np.asarray(a).nbytes for a in out) > 0
+            np.asarray(a).nbytes for a in fetched) > 0
     ((_, args, out),) = [c for c in calls if c[0] == "scan"]
     sent = [a for a in jax.tree_util.tree_leaves(args)
             if hasattr(a, "nbytes")]
@@ -249,8 +253,8 @@ def program_args():
             return fn(*args)
         return call
 
-    scheduler._batched_planner = lambda kind: spy(
-        ("plan", kind), batched(kind))
+    scheduler._batched_planner = lambda sig: spy(
+        ("plan", sig), batched(sig))
     megaloop._scan_fn = lambda kind, *flags: spy(
         ("scan", kind), scan_fn(kind, *flags))
     try:
@@ -259,13 +263,13 @@ def program_args():
         rt.run_scanned(START + 1, 4)
     finally:
         scheduler._batched_planner, megaloop._scan_fn = batched, scan_fn
-    return {name: (kind, args) for (name, kind), args in calls.items()}
+    return {name: (key, args) for (name, key), args in calls.items()}
 
 
 def test_compiled_programs_carry_the_scopes(program_args):
-    kind, args = program_args["plan"]
+    sig, args = program_args["plan"]
     with jax.enable_x64(True):
-        text = scheduler._batched_planner(kind).lower(
+        text = scheduler._batched_planner(sig).lower(
             *args).compile().as_text()
     names = _op_names(text)
     assert any(n.startswith("jit(green_planner)/") for n in names)
