@@ -34,7 +34,7 @@ import os
 import time
 from dataclasses import dataclass, field
 from enum import Enum
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from repro.core.lowering import (
     ScenarioBatch,
@@ -123,6 +123,16 @@ class RuntimeConfig:
     # Scenario-sigma widening per stale hour for zones whose carbon feed
     # is dark: sigma = 0.10 * (1 + widen * staleness).
     fault_sigma_widen: float = 0.05
+
+
+class GateDecision(NamedTuple):
+    """The hysteresis gate's verdict on one candidate: whether to switch,
+    and the switch's migrations, restarts and charge in grams."""
+
+    switch: bool
+    migrations: int
+    restarts: int
+    migration_g: float
 
 
 @dataclass
@@ -826,24 +836,46 @@ class ContinuumRuntime:
         adopted regardless of the saving-vs-cost comparison (evacuating
         a dead node must never lose to flap damping), but migration and
         restart costs are still counted and billed in full.
+
+        The decision (:meth:`gate_decision`) and the switch
+        (:meth:`adopt`) are separate steps, so a caller that must check
+        the decision against shared capacity first (the fleet runtime)
+        can hold instead.
         """
+        d = self.gate_decision(cand, saving_g, force=force)
+        if not d.switch:
+            return False, 0, 0, 0.0, ()
+        cells = self.adopt(cand, want_cells=want_cells)
+        return True, d.migrations, d.restarts, d.migration_g, cells
+
+    def gate_decision(self, cand: Dict[str, Tuple[str, str]],
+                      saving_g: float, force: bool = False
+                      ) -> GateDecision:
+        """The gate's verdict on ``cand`` against ``self.current``,
+        without switching (see :meth:`hysteresis_gate`)."""
         cfg = self.config
         if self.current is None:
-            self.current = cand
-            return True, len(cand), 0, 0.0, ()
+            return GateDecision(True, len(cand), 0, 0.0)
         if cand == self.current:
-            return False, 0, 0, 0.0, ()
+            return GateDecision(False, 0, 0, 0.0)
         moved = self._moved(self.current, cand)
         flapped = self._flapped(self.current, cand)
         cost = cfg.migration_g * moved + cfg.restart_g * flapped
         hyst = 0.0 if cfg.oracle else cfg.hysteresis_g
         if force or saving_g > cost + hyst:
-            cells = _migration_cells(
-                self.current, cand, cfg.migration_g, cfg.restart_g) \
-                if want_cells else ()
-            self.current = cand
-            return True, moved, flapped, cost, cells
-        return False, 0, 0, 0.0, ()
+            return GateDecision(True, moved, flapped, cost)
+        return GateDecision(False, 0, 0, 0.0)
+
+    def adopt(self, cand: Dict[str, Tuple[str, str]],
+              want_cells: bool = False) -> Tuple:
+        """Make ``cand`` the incumbent; returns the switch's charge cells
+        (none for the initial rollout, or unless ``want_cells``)."""
+        cfg = self.config
+        cells = _migration_cells(
+            self.current, cand, cfg.migration_g, cfg.restart_g) \
+            if want_cells and self.current is not None else ()
+        self.current = cand
+        return cells
 
     @staticmethod
     def _moved(old: Dict[str, Tuple[str, str]],

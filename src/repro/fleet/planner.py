@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -67,6 +67,7 @@ from repro.core.scheduler import (
 from .problem import (
     FleetProblem,
     FleetResult,
+    FleetStage,
     FleetStats,
     _CAP_EPS,
     empty_capacity_report,
@@ -361,100 +362,189 @@ def _chunks(seq: List[_Prep], size: int):
 # ---------------------------------------------------------------------------
 
 
-def _run_group(kind: str, preps: List[_Prep], bucket: BucketSpec, cfg,
-               max_batch: int, devices: Tuple, stats: FleetStats,
-               green_pen: Optional[float] = None,
-               penalties: Optional[List] = None) -> None:
-    """Run one same-shape group through the uncoupled program, chunked
-    along the app axis; writes each prep's ``out`` row in place."""
-    import jax
+@dataclass
+class _Call:
+    """One chunk of apps stacked into a program's arguments."""
 
-    gp = cfg.green_penalty if green_pen is None else green_pen
+    chunk: List[_Prep]
+    args: Tuple
+    sig: Tuple
+    A_chunk: int
+
+
+def _nbytes(args) -> int:
+    """Bytes of a call's arguments: arrays by size, scalars as 8."""
+    n = 0
+    for a in args:
+        if isinstance(a, tuple):
+            n += _nbytes(a)
+        else:
+            n += a.nbytes if isinstance(a, np.ndarray) else 8
+    return n
+
+
+def _stack_group(kind: str, preps: List[_Prep], bucket: BucketSpec, cfg,
+                 max_batch: int, n_dev: int, gp: float,
+                 penalties: Optional[Callable] = None):
+    """One same-shape group's calls of the uncoupled program, chunked
+    along the app axis (phantom apps fill each chunk up to its bucket and
+    a multiple of the devices); each chunk's ``penalties`` (a function of
+    the chunk's preps) are folded and its arguments stacked only as the
+    call is taken, so one chunk's arguments are held at a time."""
     argc = PLANNER_COMM_ARGC[kind]
-    n_dev = len(devices)
-    use_shard = n_dev > 1
-    fn = _sharded_program(kind, devices) if use_shard \
-        else _uncoupled_program(kind)
-    pos = 0
     for chunk in _chunks(preps, max_batch):
-        pens = penalties[pos:pos + len(chunk)] if penalties else None
-        pos += len(chunk)
-        A_real = len(chunk)
-        # phantom apps fill the app axis up to a multiple of the devices
-        A_chunk = -(-bucket.pad_apps(A_real) // n_dev) * n_dev
+        pens = penalties(chunk) if penalties else None
+        A_chunk = -(-bucket.pad_apps(len(chunk)) // n_dev) * n_dev
         shared, stacked = _chunk_args(chunk, A_chunk, pens)
         ci, ci_mean, cpu_cap, ram_cap, cost = shared
         E, order = stacked[:2]
-        wp, wf, wn, wcpu, wram = stacked[2:7]
+        warm = stacked[2:7]
         comm = stacked[7:7 + argc]
         P_s, A_s, sf_s, cpur, ramr, must_s, ms = stacked[7 + argc:]
-        dims = chunk[0].dims
-        sig = ("fleet", kind, A_chunk) + dims + (
-            (n_dev,) if use_shard else ())
-        t0 = time.perf_counter()
-        with jax.enable_x64(True), jax.default_device(devices[0]):
-            out = fn(ci, ci_mean, E, order, wp, wf, wn, wcpu, wram,
-                     *comm, P_s, A_s, sf_s, cpur, ramr, cpu_cap, ram_cap,
-                     must_s, cost, cfg.money_weight, cfg.pref_weight,
-                     cfg.emission_weight, gp, ms)
-        outs = [np.asarray(o) for o in out]
-        dt = time.perf_counter() - t0
-        compiled = COMPILE_CACHE.record(sig, dt)
-        stats.calls += 1
-        stats.compiles += int(compiled)
-        stats.plan_time_s += dt
-        stats.padded_apps += A_chunk - A_real
-        stats.sharded = use_shard
-        for i, prep in enumerate(chunk):
-            prep.out = tuple(o[i] for o in outs)
-            prep.sig, prep.plan_time_s, prep.compiled = sig, dt, compiled
+        args = (ci, ci_mean, E, order, *warm, *comm, P_s, A_s, sf_s, cpur,
+                ramr, cpu_cap, ram_cap, must_s, cost, cfg.money_weight,
+                cfg.pref_weight, cfg.emission_weight, gp, ms)
+        sig = ("fleet", kind, A_chunk) + chunk[0].dims + (
+            (n_dev,) if n_dev > 1 else ())
+        yield _Call(chunk, args, sig, A_chunk)
+
+
+def _folded(calls, stage: FleetStage):
+    """``calls`` one at a time, the time each took to fold and stack
+    recorded as a ``fleet.fold`` under ``stage``."""
+    it = iter(calls)
+    while True:
+        t0 = stage.children[-1].t1 if stage.children else stage.t0
+        call = next(it, None)
+        if call is None:
+            return
+        stage.children.append(
+            FleetStage("fleet.fold", t0, time.perf_counter()))
+        yield call
+
+
+def _execute(fn, call: _Call, device, stats: FleetStats,
+             stage: FleetStage, unpack, lead: Tuple = ()):
+    """Run one call (``lead`` arguments first), copy its outputs to the
+    host and hand them to ``unpack``, as ``fleet.dispatch``,
+    ``fleet.wait`` and ``fleet.fetch`` under ``stage``; returns what
+    ``unpack`` returns."""
+    import jax
+
+    # each part of a stage opens where the one before it closed
+    t0 = stage.children[-1].t1 if stage.children else time.perf_counter()
+    with jax.enable_x64(True), jax.default_device(device):
+        out = fn(*lead, *call.args)
+    t1 = time.perf_counter()
+    jax.block_until_ready(out)
+    t2 = time.perf_counter()
+    outs = [np.asarray(o) for o in jax.tree_util.tree_leaves(out)]
+    rest = unpack(outs)
+    t3 = time.perf_counter()
+    stage.children += [
+        FleetStage("fleet.dispatch", t0, t1,
+                   {"h2d_bytes": _nbytes(lead) + _nbytes(call.args)}),
+        FleetStage("fleet.wait", t1, t2),
+        FleetStage("fleet.fetch", t2, t3,
+                   {"d2h_bytes": sum(o.nbytes for o in outs)})]
+    dt = t3 - t0
+    compiled = COMPILE_CACHE.record(call.sig, dt)
+    stats.calls += 1
+    stats.compiles += int(compiled)
+    stats.plan_time_s += dt
+    stats.padded_apps += call.A_chunk - len(call.chunk)
+    for prep in call.chunk:
+        prep.sig, prep.plan_time_s, prep.compiled = call.sig, dt, compiled
+    return rest
+
+
+def _run_calls(kind: str, calls, devices: Tuple, stats: FleetStats,
+               stage: FleetStage) -> None:
+    """Run one group's calls of the uncoupled program in order; writes
+    each prep's ``out`` row in place."""
+    use_shard = len(devices) > 1
+    fn = _sharded_program(kind, devices) if use_shard \
+        else _uncoupled_program(kind)
+    stats.sharded = use_shard
+    for call in _folded(calls, stage):
+        def unpack(outs, chunk=call.chunk):
+            for i, prep in enumerate(chunk):
+                prep.out = tuple(o[i] for o in outs)
+
+        _execute(fn, call, devices[0], stats, stage, unpack)
+
+
+def _stage(name: str, stats: FleetStats,
+           t0: Optional[float] = None) -> FleetStage:
+    """A new stage of the call, opening where the one before it closed."""
+    if t0 is None:
+        t0 = stats.stages[-1].t1 if stats.stages else time.perf_counter()
+    stage = FleetStage(name, t0)
+    stats.stages.append(stage)
+    return stage
+
+
+def _run_uncoupled(groups: Dict[Tuple, List[_Prep]], bucket: BucketSpec,
+                   cfg, max_batch: int, devices: Tuple, stats: FleetStats,
+                   penalties: Optional[Callable] = None,
+                   green_pen: Optional[float] = None) -> FleetStage:
+    """One pass of every group through the uncoupled program, each chunk
+    folded with the ``penalties`` (a function of the chunk's preps) and
+    stacked (``fleet.fold``) before its call; returns the pass's
+    ``fleet.round`` stage, left open for the caller to close."""
+    rnd = _stage("fleet.round", stats)
+    gp = cfg.green_penalty if green_pen is None else green_pen
+    for (kind, *_dims), preps in groups.items():
+        _run_calls(kind, _stack_group(kind, preps, bucket, cfg, max_batch,
+                                      len(devices), gp, penalties),
+                   devices, stats, rnd)
+    return rnd
 
 
 def _run_waterfill(fleet: FleetProblem, preps: List[_Prep],
                    bucket: BucketSpec, cfg, max_batch: int, device,
-                   stats: FleetStats) -> None:
+                   stats: FleetStats,
+                   used: Optional[Tuple[np.ndarray, np.ndarray]] = None
+                   ) -> None:
     """Priority-ordered waterfill over all apps (one shared padded shape),
     chunked along the app axis with the node-load carry threaded across
-    chunks host-side.  The scan is sequential over apps, so it runs on
-    one device."""
-    import jax
-
+    chunks host-side, from the loads ``used`` (default none).  The scan
+    is sequential over apps, so it runs on one device."""
+    rnd = _stage("fleet.round", stats)
     kind = preps[0].low.comm.kind
-    argc = PLANNER_COMM_ARGC[kind]
     order = [i for i in fleet.waterfill_order()]
     by_idx = {p.idx: p for p in preps}
     ordered = [by_idx[i] for i in order if i in by_idx]
     N_p = preps[0].dims[2]
-    cpu_used = np.zeros(N_p)
-    ram_used = np.zeros(N_p)
+    cpu_used = np.zeros(N_p) if used is None \
+        else _pad1(np.asarray(used[0], dtype=float), N_p)
+    ram_used = np.zeros(N_p) if used is None \
+        else _pad1(np.asarray(used[1], dtype=float), N_p)
     fn = _waterfill_program(kind)
-    for chunk in _chunks(ordered, max_batch):
-        A_real = len(chunk)
-        A_chunk = bucket.pad_apps(A_real)
-        shared, stacked = _chunk_args(chunk, A_chunk, None)
-        ci, ci_mean, cpu_cap, ram_cap, cost = shared
-        dims = chunk[0].dims
-        sig = ("fleet_wf", kind, A_chunk) + dims
-        t0 = time.perf_counter()
-        with jax.enable_x64(True), jax.default_device(device):
-            cpu_out, ram_out, ys = fn(
-                cpu_used, ram_used, ci, ci_mean, cpu_cap, ram_cap, cost,
-                cfg.money_weight, cfg.pref_weight, cfg.emission_weight,
-                cfg.green_penalty, stacked)
-        ys = [np.asarray(y) for y in ys]
-        cpu_used = np.asarray(cpu_out)
-        ram_used = np.asarray(ram_out)
-        dt = time.perf_counter() - t0
-        compiled = COMPILE_CACHE.record(sig, dt)
-        stats.calls += 1
-        stats.compiles += int(compiled)
-        stats.plan_time_s += dt
-        stats.padded_apps += A_chunk - A_real
-        for i, prep in enumerate(chunk):
-            prep.out = tuple(y[i] for y in ys[:6])
-            if ys[6][i]:
-                prep.extra_note = _WF_WARM_NOTE
-            prep.sig, prep.plan_time_s, prep.compiled = sig, dt, compiled
+
+    def calls():
+        for chunk in _chunks(ordered, max_batch):
+            A_chunk = bucket.pad_apps(len(chunk))
+            shared, stacked = _chunk_args(chunk, A_chunk, None)
+            ci, ci_mean, cpu_cap, ram_cap, cost = shared
+            args = (ci, ci_mean, cpu_cap, ram_cap, cost, cfg.money_weight,
+                    cfg.pref_weight, cfg.emission_weight, cfg.green_penalty,
+                    stacked)
+            sig = ("fleet_wf", kind, A_chunk) + chunk[0].dims
+            yield _Call(chunk, args, sig, A_chunk)
+
+    for call in _folded(calls(), rnd):
+        def unpack(outs, chunk=call.chunk):
+            cpu_out, ram_out, *ys = outs
+            for i, prep in enumerate(chunk):
+                prep.out = tuple(y[i] for y in ys[:6])
+                if ys[6][i]:
+                    prep.extra_note = _WF_WARM_NOTE
+            return cpu_out, ram_out
+
+        cpu_used, ram_used = _execute(fn, call, device, stats, rnd, unpack,
+                                      lead=(cpu_used, ram_used))
+    rnd.t1 = rnd.children[-1].t1
 
 
 def _loads_from_preps(preps: List[_Prep], N: int
@@ -515,19 +605,28 @@ def _run_price(fleet: FleetProblem, groups: Dict[Tuple, List[_Prep]],
     lam_ram = np.zeros(N)
     all_preps = [p for preps in groups.values() for p in preps]
     for _ in range(max(1, fleet.price_rounds)):
-        for (kind, *_dims), preps in groups.items():
-            pens = [_price_penalties(p, lam_cpu, lam_ram, gp, gp_eff)
-                    for p in preps]
-            _run_group(kind, preps, bucket, cfg, max_batch, devices, stats,
-                       green_pen=gp_eff, penalties=pens)
+        def pens(chunk):
+            return [_price_penalties(p, lam_cpu, lam_ram, gp, gp_eff)
+                    for p in chunk]
+
+        rnd = _run_uncoupled(groups, bucket, cfg, max_batch, devices, stats,
+                             penalties=pens, green_pen=gp_eff)
         stats.price_rounds += 1
+        t_loads = rnd.children[-1].t1
         cpu_load, ram_load = _loads_from_preps(all_preps, N)
+        stats.prices.append((lam_cpu.copy(), lam_ram.copy()))
+        stats.loads.append((cpu_load, ram_load))
         exc_cpu = np.maximum(cpu_load - cpu_cap, 0.0)
         exc_ram = np.maximum(ram_load - ram_cap, 0.0)
-        if (exc_cpu <= _CAP_EPS).all() and (exc_ram <= _CAP_EPS).all():
+        fits = bool((exc_cpu <= _CAP_EPS).all()
+                    and (exc_ram <= _CAP_EPS).all())
+        if not fits:
+            lam_cpu += fleet.price_step * exc_cpu
+            lam_ram += fleet.price_step * exc_ram
+        rnd.t1 = time.perf_counter()
+        rnd.children.append(FleetStage("fleet.loads", t_loads, rnd.t1))
+        if fits:
             break
-        lam_cpu += fleet.price_step * exc_cpu
-        lam_ram += fleet.price_step * exc_ram
 
 
 # ---------------------------------------------------------------------------
@@ -585,7 +684,9 @@ def plan_many(fleet: FleetProblem,
               scheduler: Optional[GreenScheduler] = None, *,
               bucket: Optional[BucketSpec] = None,
               max_batch: int = 256,
-              devices: Optional[Sequence] = None) -> FleetResult:
+              devices: Optional[Sequence] = None,
+              used: Optional[Tuple[np.ndarray, np.ndarray]] = None
+              ) -> FleetResult:
     """Plan every app of a :class:`FleetProblem` as batched programs.
 
     ``scheduler`` supplies the objective configuration (defaults to a
@@ -596,12 +697,21 @@ def plan_many(fleet: FleetProblem,
     peak memory against dispatch count, not compiles.  ``devices`` are
     the jax devices the programs run on (default ``jax.devices()``): the
     uncoupled and price programs shard the app axis over all of them,
-    the waterfill scan runs on the first.
+    the waterfill scan runs on the first.  ``used`` is a per-node
+    ``(cpu, ram)`` load already committed outside the fleet; the
+    waterfill coupling plans every app against the capacity left (the
+    other couplings refuse it).
 
     Returns a :class:`FleetResult` with one B=1 ``PlanResult`` per app
     (same order as ``fleet.apps``), per-app emissions, the shared-node
-    :class:`CapacityReport`, and call telemetry on ``.stats``.
+    :class:`CapacityReport`, and call telemetry on ``.stats`` (its
+    ``stages`` time the call: ``fleet.prepare``, one ``fleet.round`` per
+    pass over the apps, ``fleet.finalize``).
     """
+    t0 = time.perf_counter()
+    if used is not None and fleet.coupling != "waterfill":
+        raise ValueError("used= takes the waterfill coupling, not "
+                         f"{fleet.coupling!r}")
     scheduler = scheduler if scheduler is not None else GreenScheduler()
     cfg = scheduler.config
     bucket = bucket if bucket is not None else (
@@ -621,6 +731,7 @@ def plan_many(fleet: FleetProblem,
     devices = tuple(jax.devices() if devices is None else devices)
     if not devices:
         raise ValueError("plan_many needs at least one device")
+    prepare = _stage("fleet.prepare", stats, t0)
 
     # Shape-degenerate apps (no services / no nodes) take the scheduler's
     # host path — nothing to batch, nothing consumed.
@@ -631,36 +742,42 @@ def plan_many(fleet: FleetProblem,
         else:
             batched.append((i, p))
 
-    if batched:
-        if fleet.coupling == "waterfill":
-            dims = _fleet_dims([p for _, p in batched], bucket)
-            preps = [_prep_app(i, p, cfg, bucket, dims)
-                     for i, p in batched]
-            stats.groups = 1
-            stats.devices = 1
-            _run_waterfill(fleet, preps, bucket, cfg, max_batch,
-                           devices[0], stats)
-        else:
-            preps = [_prep_app(i, p, cfg, bucket) for i, p in batched]
-            groups: Dict[Tuple, List[_Prep]] = {}
-            for prep in preps:
-                key = (prep.low.comm.kind,) + prep.dims
-                groups.setdefault(key, []).append(prep)
-            stats.groups = len(groups)
-            stats.devices = len(devices)
-            if fleet.coupling == "price":
-                _run_price(fleet, groups, bucket, cfg, max_batch, devices,
-                           stats)
-            else:
-                for (kind, *_dims), grp in groups.items():
-                    _run_group(kind, grp, bucket, cfg, max_batch, devices,
-                               stats)
+    preps: List[_Prep] = []
+    if batched and fleet.coupling == "waterfill":
+        dims = _fleet_dims([p for _, p in batched], bucket)
+        preps = [_prep_app(i, p, cfg, bucket, dims) for i, p in batched]
+        stats.groups = 1
+        stats.devices = 1
+        prepare.t1 = time.perf_counter()
+        _run_waterfill(fleet, preps, bucket, cfg, max_batch, devices[0],
+                       stats, used)
+    elif batched:
+        preps = [_prep_app(i, p, cfg, bucket) for i, p in batched]
+        groups: Dict[Tuple, List[_Prep]] = {}
         for prep in preps:
-            results[prep.idx] = _finalize(prep)
+            key = (prep.low.comm.kind,) + prep.dims
+            groups.setdefault(key, []).append(prep)
+        stats.groups = len(groups)
+        stats.devices = len(devices)
+        prepare.t1 = time.perf_counter()
+        if fleet.coupling == "price":
+            _run_price(fleet, groups, bucket, cfg, max_batch, devices,
+                       stats)
+        else:
+            rnd = _run_uncoupled(groups, bucket, cfg, max_batch, devices,
+                                 stats)
+            rnd.t1 = rnd.children[-1].t1
+    else:
+        prepare.t1 = time.perf_counter()
 
+    finalize = _stage("fleet.finalize", stats)
+    for prep in preps:
+        results[prep.idx] = _finalize(prep)
     emissions = np.array([float(r.emissions_g[0]) for r in results]) \
         if results else np.zeros(0)
     capacity = fleet_capacity_report(fleet, results)
-    return FleetResult(
+    result = FleetResult(
         fleet=fleet, results=results, emissions_g=emissions,
         capacity=capacity, coupling=fleet.coupling, stats=stats)
+    finalize.t1 = time.perf_counter()
+    return result

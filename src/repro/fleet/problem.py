@@ -36,6 +36,7 @@ __all__ = [
     "CapacityReport",
     "FleetProblem",
     "FleetResult",
+    "FleetStage",
     "FleetStats",
 ]
 
@@ -176,6 +177,20 @@ class CapacityReport:
 
 
 @dataclass
+class FleetStage:
+    """One timed stage of a ``plan_many`` call (``time.perf_counter()``
+    stamps), with the stages inside it in order and its counters; the
+    caller turns them into spans (``FleetRuntime`` does, under
+    ``fleet.plan``)."""
+
+    name: str
+    t0: float
+    t1: float = 0.0
+    attrs: Dict[str, int] = field(default_factory=dict)
+    children: List["FleetStage"] = field(default_factory=list)
+
+
+@dataclass
 class FleetStats:
     """Telemetry of one ``plan_many`` call."""
 
@@ -188,6 +203,13 @@ class FleetStats:
     devices: int = 1
     apps: int = 0
     padded_apps: int = 0       # phantom-app rows planned and dropped
+    # fleet.prepare, one fleet.round per pass over the apps (a price
+    # round, or the single uncoupled or waterfill pass), fleet.finalize
+    stages: List[FleetStage] = field(default_factory=list)
+    # per price round: the (cpu, ram) shadow prices per node it planned
+    # with, and the (cpu, ram) fleet loads per node of its plans
+    prices: List[Tuple[np.ndarray, np.ndarray]] = field(default_factory=list)
+    loads: List[Tuple[np.ndarray, np.ndarray]] = field(default_factory=list)
 
     def to_dict(self) -> Dict[str, float]:
         return {

@@ -12,6 +12,42 @@ beats migration+restart cost plus the hysteresis margin — before
 accounting each app's ACTIVE assignment under the tick's true carbon
 intensities.
 
+The commit keeps capacity whatever the coupling: the gates' verdicts
+are committed together only where the holders' incumbents and the
+switchers' candidates fit every node together.  Otherwise each switch,
+in priority order, replaces its incumbent's load by its candidate's only
+where every node stays within capacity, and holds where not; a first
+rollout that does not fit is planned by the waterfill program into the
+capacity left, and refused where even that fails.  (An emergency tick
+adopts the coupled plan atomically instead.)
+
+Spans, with a tracer attached (``FleetRuntime(tracer=...)`` or an
+``Observability`` bundle's), one tree per tick:
+
+    fleet.tick
+    ├── fleet.ingest       per-tenant telemetry, constraints, lowering
+    │   ├── fleet.telemetry    the carbon signals and monitoring window
+    │   ├── fleet.constraints  the constraint pass
+    │   └── fleet.lower        lowering, fault masking, warm start,
+    │                          the fleet problem
+    ├── fleet.plan         (apps, padded_apps, calls, devices,
+    │   │                   price_rounds, overcommitted)
+    │   ├── fleet.prepare
+    │   ├── fleet.round    one per price round (or pass)
+    │   │   ├── fleet.fold       price fold and stacking
+    │   │   ├── fleet.dispatch   per call (h2d_bytes)
+    │   │   ├── fleet.wait       per call
+    │   │   ├── fleet.fetch      per call (d2h_bytes)
+    │   │   └── fleet.loads      load and price update (price only)
+    │   └── fleet.finalize
+    └── fleet.commit       gates, the capacity rule, accounting
+                           (switched, held, repaired, refused)
+
+The children of ``fleet.tick``, ``fleet.plan`` and each ``fleet.round``
+tile their parent in order.  The children of ``fleet.ingest`` are each
+stage's time summed over the tenants, laid end to end from the ingest's
+start (the tenants' stages interleave).
+
 Multi-tenant billing rides on the shared observability ledger: every
 app's tick entry is recorded with its tenant tag (``app=name``), so
 ``repro.obs.billing_report`` decomposes the fleet's total gCO2 into
@@ -20,15 +56,17 @@ per-tick accounted emissions.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from repro.continuum.loop import (
     ContinuumResult,
     ContinuumRuntime,
+    GateDecision,
     RuntimeConfig,
     TickRecord,
 )
@@ -43,12 +81,14 @@ from repro.core.scheduler import (
     SchedulerConfig,
 )
 from repro.core.types import Application, Infrastructure
-from repro.obs import Observability
+from repro.obs import Observability, Tracer
 
 from .planner import plan_many
 from .problem import (
+    _CAP_EPS,
     CapacityReport,
     FleetProblem,
+    FleetStage,
     FleetStats,
     accumulate_loads,
     empty_capacity_report,
@@ -80,6 +120,12 @@ class FleetTickRecord:
     planned_capacity: CapacityReport  # this tick's plan_many candidates
     plan_stats: FleetStats
     compiles: int = 0                 # XLA programs built this tick
+    # the capacity rule's outcome: switches that did not fit and held,
+    # first rollouts planned into the capacity left, and tenants left
+    # with nothing deployed
+    held: Tuple[str, ...] = ()
+    repaired: Tuple[str, ...] = ()
+    refused: Tuple[str, ...] = ()
 
     @property
     def emissions_g(self) -> float:
@@ -145,6 +191,13 @@ class FleetRuntime:
     # bit-equal to billing_report's per-tenant sums.
     watch: Optional[object] = field(default=None, repr=False)
     max_batch: int = 256
+    # the jax devices plan_many runs on (default every visible device)
+    devices: Optional[Sequence] = field(default=None, repr=False)
+    # A tracer on its own; an attached bundle's tracer takes its place.
+    tracer: Optional[Tracer] = field(default=None, repr=False)
+    # the last tick's plan_many result (its candidates, before the gates)
+    last_result: Optional[object] = field(default=None, init=False,
+                                          repr=False)
 
     def __post_init__(self) -> None:
         names = [fa.name for fa in self.apps]
@@ -173,11 +226,19 @@ class FleetRuntime:
     def runtime(self, name: str) -> ContinuumRuntime:
         return self._runtimes[name]
 
+    def active_tracer(self) -> Optional[Tracer]:
+        """The tracer the fleet tick records spans into: an enabled
+        bundle's, else the lone ``tracer``; None when neither records."""
+        tr = self.obs.tracer if (self.obs is not None and self.obs.enabled) \
+            else self.tracer
+        return tr if tr is not None and tr.enabled else None
+
     def tick(self, t: int) -> FleetTickRecord:
         cfg = self.config
         obs = self.obs if (self.obs is not None and self.obs.enabled) \
             else None
         misses0 = COMPILE_CACHE.misses
+        t_tick0 = time.perf_counter()
 
         # 1+2. per-tenant ingestion + constraint pipeline -> one problem
         # per app, warm-started from its incumbent.  With a fault
@@ -192,6 +253,10 @@ class FleetRuntime:
         outs = []
         evicted: Dict[str, int] = {}
         emergency: Dict[str, bool] = {}
+        # seconds summed over the tenants: telemetry, constraints, lowering
+        # (with the fleet problem's build)
+        ingest_s = [0.0, 0.0, 0.0]
+        t0 = t_tick0
         for fa in self.apps:
             rt = self._runtimes[fa.name]
             rt.pipeline.gatherer.signal = \
@@ -199,11 +264,13 @@ class FleetRuntime:
             rt.pipeline.gatherer.forecast = rt._carbon_view.forecast_signal(
                 t, cfg.horizon_h)
             mon = rt._workload_view.monitoring(t)
+            t1 = time.perf_counter()
             out = rt.pipeline.run(fa.app, self.infra, mon,
                                   use_kb=cfg.use_kb)
             if faults is not None \
                     and rt._workload_view.stale(t, cfg.telemetry_window):
                 out = rt._held_output(out, t)
+            t2 = time.perf_counter()
             problem = rt.pipeline.problem_for(out)
             evicted[fa.name] = 0
             emergency[fa.name] = False
@@ -231,20 +298,29 @@ class FleetRuntime:
                 problem = problem.with_warm_start(rt.current)
             problems.append(problem)
             outs.append(out)
+            t3 = time.perf_counter()
+            ingest_s[0] += t1 - t0
+            ingest_s[1] += t2 - t1
+            ingest_s[2] += t3 - t2
+            t0 = t3
 
-        # 3. one batched fleet replan (coupled capacity per ``coupling``)
-        t_plan0 = time.perf_counter()
         fleet = FleetProblem(
             apps=tuple(problems),
             names=tuple(fa.name for fa in self.apps),
             priority=tuple(fa.priority for fa in self.apps),
             coupling=self.coupling)
+        ingest_s[2] += time.perf_counter() - t0
+
+        # 3. one batched fleet replan (coupled capacity per ``coupling``)
+        t_plan0 = time.perf_counter()
         fresult = plan_many(fleet, self.scheduler,
-                            max_batch=self.max_batch)
-        replan_s = time.perf_counter() - t_plan0
+                            max_batch=self.max_batch, devices=self.devices)
+        t_plan1 = time.perf_counter()
+        replan_s = t_plan1 - t_plan0
+        self.last_result = fresult
         ci_now = self.carbon.now(self._node_regions, t)
 
-        # 4+5. per-tenant hysteresis gate + accounting under the true CI.
+        # 4. per-tenant hysteresis gate, decided before anything switches.
         # An emergency anywhere forces the WHOLE fleet's coupled plan:
         # plan_many's candidates are only jointly capacity-feasible as a
         # set, so letting one tenant's flap damping hold its incumbent
@@ -255,44 +331,69 @@ class FleetRuntime:
         if fleet_force:
             for fa in self.apps:
                 emergency[fa.name] = True
+        cands: List[Optional[Dict[str, Tuple[str, str]]]] = []
+        decisions: List[Optional[GateDecision]] = []
+        savings: List[float] = []
+        for i, fa in enumerate(self.apps):
+            rt = self._runtimes[fa.name]
+            plan = fresult.results[i].plans[0]
+            cand, decision, saving = None, None, 0.0
+            if plan.feasible:
+                cand = plan_assignment(plan)
+                if rt.current is not None and cand != rt.current:
+                    # expected saving under the tick's MONITORED signal
+                    # (low.ci): candidate emissions are exactly the
+                    # planner's per-app value, the incumbent re-priced
+                    # on the same lowering
+                    low = problems[i].lowering
+                    cur_g = lowered_emissions(
+                        low, *assignment_arrays(low, rt.current))
+                    saving = (cur_g - float(fresult.emissions_g[i])) \
+                        * cfg.horizon_h
+                decision = rt.gate_decision(cand, saving,
+                                            force=emergency[fa.name])
+            cands.append(cand)
+            decisions.append(decision)
+            savings.append(saving)
+
+        # 5. the switches that keep capacity, then first rollouts that
+        # did not fit planned into the capacity left
+        adopt, held, deferred, used = self._fit(
+            fleet, problems, cands, decisions, fleet_force)
+        repairs = self._repair(fleet, deferred, used) if deferred else {}
+
+        # 6. switch and account each tenant's ACTIVE assignment under the
+        # tick's true CI
         records: Dict[str, TickRecord] = {}
         cpu_load = np.zeros(len(self._node_regions))
         ram_load = np.zeros(len(self._node_regions))
         viols_before = len(self.placement_violations)
+        refused: List[str] = []
         for i, fa in enumerate(self.apps):
             rt = self._runtimes[fa.name]
             low = problems[i].lowering
-            pres = fresult.results[i]
-            plan = pres.plans[0]
+            plan = fresult.results[i].plans[0]
             warm_rejected = any(
                 "warm start rejected" in n for n in plan.notes)
             switched = False
             migrations = restarts = 0
             charged_moved = charged_flapped = 0
             migration_g = 0.0
-            expected_saving = 0.0
             mig_cells: Tuple = ()
-            if plan.feasible:
-                cand = plan_assignment(plan)
-                saving = 0.0
-                if rt.current is not None and cand != rt.current:
-                    # expected saving under the tick's MONITORED signal
-                    # (low.ci): candidate emissions are exactly the
-                    # planner's per-app value, the incumbent re-priced
-                    # on the same lowering
-                    cur_g = lowered_emissions(
-                        low, *assignment_arrays(low, rt.current))
-                    saving = (cur_g - float(pres.emissions_g[0])) \
-                        * cfg.horizon_h
-                    expected_saving = saving
+            if i in repairs:
+                switched, migrations = True, len(repairs[i])
+                rt.adopt(repairs[i])
+            elif i in adopt:
+                d = decisions[i]
                 initial = rt.current is None
-                (switched, migrations, restarts, migration_g,
-                 mig_cells) = rt.hysteresis_gate(
-                    cand, saving, want_cells=obs is not None,
-                    force=emergency[fa.name])
-                if switched and not initial:
+                mig_cells = rt.adopt(cands[i], want_cells=obs is not None)
+                switched, migrations, restarts, migration_g = \
+                    True, d.migrations, d.restarts, d.migration_g
+                if not initial:
                     charged_moved = migrations
                     charged_flapped = restarts
+            if not rt.current and low.S:
+                refused.append(fa.name)
             emissions = 0.0
             placed = fcur = ncur = None
             viols: List[PlacementViolation] = []
@@ -314,7 +415,7 @@ class FleetRuntime:
             records[fa.name] = TickRecord(
                 t=t, emissions_g=emissions, migration_g=migration_g,
                 migrations=migrations, replanned=True, switched=switched,
-                expected_saving_g=expected_saving,
+                expected_saving_g=savings[i],
                 n_constraints=len(outs[i].constraints),
                 warm_start_rejected=warm_rejected, restarts=restarts,
                 replan_s=replan_s, evicted=evicted[fa.name],
@@ -353,11 +454,112 @@ class FleetRuntime:
             self.watch.observe_fleet_tick(
                 t, records, ci_now,
                 registry=obs.registry if obs is not None else None)
-        return FleetTickRecord(
+        names = fleet.names
+        frec = FleetTickRecord(
             t=t, records=records, capacity=capacity,
             planned_capacity=fresult.capacity,
             plan_stats=fresult.stats,
-            compiles=COMPILE_CACHE.misses - misses0)
+            compiles=COMPILE_CACHE.misses - misses0,
+            held=tuple(names[i] for i in held),
+            repaired=tuple(names[i] for i in sorted(repairs)),
+            refused=tuple(refused))
+        tr = self.active_tracer()
+        if tr is not None:
+            t_commit1 = time.perf_counter()
+            st = fresult.stats
+            # the plan is what its stages time: the call's own entry and
+            # return fall to the ingest and the commit
+            t_plan0, t_plan1 = st.stages[0].t0, st.stages[-1].t1
+            tid = tr.add("fleet.tick", t_tick0, t_commit1, t=t)
+            iid = tr.add("fleet.ingest", t_tick0, t_plan0, parent=tid)
+            t0 = t_tick0
+            for name, dt in zip(_INGEST_SPANS, ingest_s):
+                tr.add(name, t0, t0 + dt, parent=iid)
+                t0 += dt
+            pid = tr.add("fleet.plan", t_plan0, t_plan1, parent=tid,
+                         apps=st.apps, padded_apps=st.padded_apps,
+                         calls=st.calls, devices=st.devices,
+                         price_rounds=st.price_rounds,
+                         overcommitted=fresult.capacity.violations)
+            _stage_spans(tr, pid, st.stages)
+            tr.add("fleet.commit", t_plan1, t_commit1, parent=tid,
+                   switched=sum(r.switched for r in records.values()),
+                   held=len(frec.held), repaired=len(frec.repaired),
+                   refused=len(frec.refused))
+        return frec
+
+    def _fit(self, fleet: FleetProblem, problems, cands, decisions,
+             force: bool) -> Tuple[Set[int], List[int], List[int], Tuple]:
+        """The switches the capacity allows: ``(adopt, held, deferred,
+        used)``.  ``deferred`` are first rollouts that did not fit,
+        ``used`` the per-node ``(cpu, ram)`` loads once ``adopt`` has
+        switched and the others hold."""
+        switching = [i for i, d in enumerate(decisions)
+                     if d is not None and d.switch]
+        if force or not problems:
+            return set(switching), [], [], ()
+        N = len(self._node_regions)
+        cap = (np.asarray(problems[0].lowering.cpu_cap, dtype=float),
+               np.asarray(problems[0].lowering.ram_cap, dtype=float))
+
+        def load(i, assign):
+            cpu, ram = np.zeros(N), np.zeros(N)
+            if assign:
+                low = problems[i].lowering
+                accumulate_loads(low, *assignment_arrays(low, assign),
+                                 cpu, ram)
+            return cpu, ram
+
+        inc = [load(i, self._runtimes[fa.name].current)
+               for i, fa in enumerate(self.apps)]
+        new = {i: load(i, cands[i]) for i in switching}
+        together = [sum(new[i][k] if i in new else inc[i][k]
+                        for i in range(len(inc))) for k in (0, 1)]
+        if all((together[k] <= cap[k] + _CAP_EPS).all() for k in (0, 1)):
+            return set(switching), [], [], tuple(together)
+        # from every incumbent (they fit, by the last tick's commit), each
+        # switch in priority order where every node it loads stays within
+        # capacity; a node already past it may only get lighter
+        used = [sum(inc[i][k] for i in range(len(inc))) for k in (0, 1)]
+        adopt: Set[int] = set()
+        held: List[int] = []
+        deferred: List[int] = []
+        for i in fleet.waterfill_order():
+            if i not in new:
+                continue
+            trial = [used[k] - inc[i][k] + new[i][k] for k in (0, 1)]
+            if all(((trial[k] <= cap[k] + _CAP_EPS)
+                    | (trial[k] <= used[k])).all() for k in (0, 1)):
+                used = trial
+                adopt.add(i)
+            elif self._runtimes[fleet.names[i]].current is None:
+                deferred.append(i)
+            else:
+                held.append(i)
+        return adopt, held, deferred, tuple(used)
+
+    def _repair(self, fleet: FleetProblem, deferred: List[int],
+                used: Tuple) -> Dict[int, Dict[str, Tuple[str, str]]]:
+        """First rollouts that did not fit, planned by the waterfill
+        program into the capacity ``used`` leaves, in priority order;
+        each chunk padded to ``max_batch`` apps, so every repair runs one
+        compiled program.  Returns the feasible plans by tenant index."""
+        sched = self.scheduler
+        bucket = sched.config.bucket if sched.config.bucket is not None \
+            else BucketSpec()
+        sub = FleetProblem(
+            apps=tuple(fleet.apps[i] for i in deferred),
+            names=tuple(fleet.names[i] for i in deferred),
+            priority=tuple(fleet.priority[i] for i in deferred),
+            coupling="waterfill")
+        devices = None if self.devices is None else self.devices[:1]
+        res = plan_many(sub, sched, max_batch=self.max_batch,
+                        bucket=dataclasses.replace(bucket,
+                                                   a=(self.max_batch,)),
+                        devices=devices, used=used)
+        return {i: plan_assignment(r.plans[0])
+                for i, r in zip(deferred, res.results)
+                if r.plans[0].feasible}
 
     def run(self, start: int, ticks: int) -> FleetRunResult:
         saved = {
@@ -379,3 +581,13 @@ class FleetRuntime:
                     self._runtimes[fa.name].current or {}))
             for fa in self.apps}
         return FleetRunResult(ticks=frecs, results=results)
+
+
+_INGEST_SPANS = ("fleet.telemetry", "fleet.constraints", "fleet.lower")
+
+
+def _stage_spans(tr: Tracer, parent: int, stages: List[FleetStage]) -> None:
+    """A ``plan_many`` call's timed stages as spans under ``parent``."""
+    for st in stages:
+        sid = tr.add(st.name, st.t0, st.t1, parent=parent, **st.attrs)
+        _stage_spans(tr, sid, st.children)
