@@ -173,9 +173,15 @@ class GreenConstraintPipeline:
         infra: Infrastructure,
         monitoring: MonitoringData,
         use_kb: bool = True,
+        *,
+        enriched: Optional[Infrastructure] = None,
     ) -> GeneratorOutput:
+        """One iteration of the loop.  ``enriched`` is ``infra`` as this
+        pipeline's gatherer would enrich it, computed once by a caller
+        that runs many pipelines on one infrastructure and carbon signal
+        (the fleet tick); given, the gatherer is not called."""
         self.iteration += 1
-        infra = self.gatherer.enrich(infra)
+        infra = self.gatherer.enrich(infra) if enriched is None else enriched
         app = self.estimator.enrich(app, monitoring)
         computation = self.estimator.computation_profiles(monitoring)
         communication = self.estimator.communication_profiles(monitoring)

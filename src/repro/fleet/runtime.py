@@ -26,7 +26,9 @@ Spans, with a tracer attached (``FleetRuntime(tracer=...)`` or an
 
     fleet.tick
     ├── fleet.ingest       per-tenant telemetry, constraints, lowering
-    │   ├── fleet.telemetry    the carbon signals and monitoring window
+    │   │                  (enrichments, enrich_shared)
+    │   ├── fleet.telemetry    the carbon signals, the shared machines'
+    │   │                      enrichment and the monitoring window
     │   ├── fleet.constraints  the constraint pass
     │   └── fleet.lower        lowering, fault masking, warm start,
     │                          the fleet problem
@@ -256,17 +258,27 @@ class FleetRuntime:
         # seconds summed over the tenants: telemetry, constraints, lowering
         # (with the fleet problem's build)
         ingest_s = [0.0, 0.0, 0.0]
+        # The gatherer's enrichment of the shared machines, once for each
+        # group of tenants that would compute the same one: the same
+        # carbon view (without a fault schedule, the fleet's own trace)
+        # and gatherer settings; the tick and horizon are the fleet's.
+        enriched: Dict[Tuple, Tuple[object, Infrastructure]] = {}
         t0 = t_tick0
         for fa in self.apps:
             rt = self._runtimes[fa.name]
-            rt.pipeline.gatherer.signal = \
-                rt._carbon_view.history_signal(t)
-            rt.pipeline.gatherer.forecast = rt._carbon_view.forecast_signal(
-                t, cfg.horizon_h)
+            view, gatherer = rt._carbon_view, rt.pipeline.gatherer
+            gatherer.signal = view.history_signal(t)
+            gatherer.forecast = view.forecast_signal(t, cfg.horizon_h)
+            key = (id(view), type(gatherer), gatherer.window,
+                   gatherer.forecast_from_history)
+            if key not in enriched:
+                # the view is held so that its id stays its own all tick
+                enriched[key] = (view, gatherer.enrich(self.infra))
             mon = rt._workload_view.monitoring(t)
             t1 = time.perf_counter()
             out = rt.pipeline.run(fa.app, self.infra, mon,
-                                  use_kb=cfg.use_kb)
+                                  use_kb=cfg.use_kb,
+                                  enriched=enriched[key][1])
             if faults is not None \
                     and rt._workload_view.stale(t, cfg.telemetry_window):
                 out = rt._held_output(out, t)
@@ -471,7 +483,9 @@ class FleetRuntime:
             # return fall to the ingest and the commit
             t_plan0, t_plan1 = st.stages[0].t0, st.stages[-1].t1
             tid = tr.add("fleet.tick", t_tick0, t_commit1, t=t)
-            iid = tr.add("fleet.ingest", t_tick0, t_plan0, parent=tid)
+            iid = tr.add("fleet.ingest", t_tick0, t_plan0, parent=tid,
+                         enrichments=len(enriched),
+                         enrich_shared=len(self.apps) - len(enriched))
             t0 = t_tick0
             for name, dt in zip(_INGEST_SPANS, ingest_s):
                 tr.add(name, t0, t0 + dt, parent=iid)

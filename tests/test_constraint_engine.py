@@ -115,6 +115,35 @@ def test_unknown_engine_rejected():
         GreenConstraintPipeline(engine="nope").run(app, infra, mon)
 
 
+@pytest.mark.parametrize("window", [1, 3])
+def test_pre_enriched_infra_matches_own_enrichment(window, monkeypatch):
+    """``run(..., enriched=...)`` handed the infrastructure its gatherer
+    would build gives what ``run`` gives when it enriches, tick for tick
+    through the telemetry window, and does not call the gatherer."""
+    app, infra = _app(6), _infra()
+    tr = CarbonTrace(REGION_PRESETS, hours=60, seed=5)
+    wl = WorkloadTrace(app, seed=5)
+    own, handed = (GreenConstraintPipeline(telemetry_window=window)
+                   for _ in range(2))
+    for t in range(24, 30):
+        for pipe in (own, handed):
+            pipe.gatherer.signal = tr.history_signal(t)
+            pipe.gatherer.forecast = tr.forecast_signal(t, 6)
+        enriched = handed.gatherer.enrich(infra)
+        mon = wl.monitoring(t)
+        a = own.run(app, infra, mon)
+        with monkeypatch.context() as m:
+            m.setattr(handed.gatherer, "enrich", None)
+            b = handed.run(app, infra, mon, enriched=enriched)
+        assert b.infra is enriched and a.infra == b.infra
+        assert a.constraints == b.constraints
+        assert (a.computation, a.communication) \
+            == (b.computation, b.communication)
+        la, lb = own.problem_for(a).lowering, handed.problem_for(b).lowering
+        assert la.E.tobytes() == lb.E.tobytes()
+        assert la.ci.tobytes() == lb.ci.tobytes()
+
+
 # ---------------------------------------------------------------------------
 # mu-decay ticks on a drifting continuum trace
 # ---------------------------------------------------------------------------
