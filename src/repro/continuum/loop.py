@@ -28,6 +28,7 @@ Each tick (= one observation window, one hour):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -349,6 +350,32 @@ def _migration_cells(old: Dict[str, Tuple[str, str]],
     return tuple(cells)
 
 
+@contextlib.contextmanager
+def kept_hooks(*gatherers):
+    """Restore the gatherers' ``signal``/``forecast`` hooks on exit: a
+    trace's closures never leak into later uses of a shared pipeline."""
+    saved = [(g.signal, g.forecast) for g in gatherers]
+    try:
+        yield
+    finally:
+        for g, (signal, forecast) in zip(gatherers, saved):
+            g.signal, g.forecast = signal, forecast
+
+
+def account(low, placed, fcur, ncur, ci, *, alive=None, t: int = -1,
+            validate: bool = True, cpu_load=None, ram_load=None
+            ) -> Tuple[float, List[PlacementViolation]]:
+    """``(emissions, violations)`` of a committed assignment given as
+    arrays: its emissions under the TRUE ``ci``, and (if ``validate``) the
+    post-plan invariant violations by :func:`~repro.faults.check_placement`,
+    whose ``cpu_load``/``ram_load`` these are."""
+    emissions = lowered_emissions(low, placed, fcur, ncur, ci=ci)
+    violations = check_placement(
+        low, placed, fcur, ncur, alive=alive, t=t, cpu_load=cpu_load,
+        ram_load=ram_load) if validate else []
+    return emissions, violations
+
+
 @dataclass
 class ContinuumRuntime:
     """Drives the adaptive loop over synchronized carbon/workload traces."""
@@ -402,8 +429,8 @@ class ContinuumRuntime:
         # is read through the degraded views (the raw traces keep backing
         # accounting/oracle truth inside the views); without one the
         # views ARE the raw traces, so the fault-free path is unchanged.
-        # The views themselves are built lazily by the _carbon_view /
-        # _workload_view properties so that reassigning runtime.carbon /
+        # The views themselves are built lazily by the carbon_view /
+        # workload_view properties so that reassigning runtime.carbon /
         # runtime.workload mid-life (tests do) stays supported.
         if self.config.faults is not None:
             self.config.faults.check_infra(self.infra)
@@ -419,7 +446,7 @@ class ContinuumRuntime:
         self.auto_bucket: Optional[BucketSpec] = None
 
     @property
-    def _carbon_view(self):
+    def carbon_view(self):
         """The carbon trace the PLANNER reads: the raw trace without a
         fault schedule, else a cached :class:`DegradedCarbon` rebuilt
         whenever ``self.carbon``/``config.faults`` are repointed."""
@@ -436,8 +463,8 @@ class ContinuumRuntime:
         return view
 
     @property
-    def _workload_view(self):
-        """Workload twin of :attr:`_carbon_view`."""
+    def workload_view(self):
+        """Workload twin of :attr:`carbon_view`."""
         faults = self.config.faults
         if faults is None:
             return self.workload
@@ -476,30 +503,14 @@ class ContinuumRuntime:
         # read is ~50 ns); spans materialize from them only when a tracer
         # is attached.
         t_tick0 = time.perf_counter()
-        # 1. monitoring + carbon ingestion: the gatherer reads the signal
-        # as of this tick (window mean -> node.carbon, persistence
-        # forecast).  With a fault schedule these views are the DEGRADED
-        # world: dark zones report persistence, dropout ticks deliver
-        # NaN-valued samples with stable identities.
-        self.pipeline.gatherer.signal = self._carbon_view.history_signal(t)
-        self.pipeline.gatherer.forecast = self._carbon_view.forecast_signal(
-            t, cfg.horizon_h)
-        mon = self._workload_view.monitoring(t)
+        # 1. monitoring + carbon ingestion, through the fault views
+        mon = self.ingest(t)
         t_ingest1 = time.perf_counter()
 
         # 2. constraints + enriched problem (KB decay happens inside); one
         # PlacementProblem per tick, lowering cached by the pipeline (the
         # delta fast path array-substitutes ci/E when only profiles moved)
-        out = self.pipeline.run(self.app, self.infra, mon,
-                                use_kb=cfg.use_kb)
-        faults = cfg.faults
-        if faults is not None \
-                and self._workload_view.stale(t, cfg.telemetry_window):
-            # telemetry dropout: the engine above already saw the NaN
-            # samples (fresh constraints come up empty, KB mu-decays),
-            # but the LOWERING must not price NaN profiles — hold the
-            # last clean window's profiles instead
-            out = self._held_output(out, t)
+        out = self.constraint_pass(mon, t)
         t_cons1 = time.perf_counter()
         cstats = getattr(self.pipeline, "constraint_stats", None) or {}
         constraint_s = float(cstats.get("constraint_s", 0.0))
@@ -509,7 +520,6 @@ class ContinuumRuntime:
         t_replan0 = time.perf_counter()
         problem = self.pipeline.problem_for(out)
         rebuild_s = time.perf_counter() - t_replan0
-        low = problem.lowering
         stats1 = self.pipeline.lowering_stats
         if stats1["delta_substitutions"] > stats0["delta_substitutions"]:
             lowering_path = "delta"
@@ -518,19 +528,14 @@ class ContinuumRuntime:
         else:
             lowering_path = "full"
 
-        # fault-handling stage: mask dead/derated nodes out of the
-        # lowering via the availability path, evict stranded services,
-        # and decide whether this tick is an emergency
-        alive = None
-        evicted = 0
-        emergency = False
-        derate = None
+        # fault-handling stage on the tick's liveness and derates
+        faults = cfg.faults
+        alive = derate = None
         fault_alive = None          # raw fault mask (pre watch feedback)
         watch = self.watch
         if faults is not None:
-            fault_alive = faults.alive_at(t)
+            fault_alive = alive = faults.alive_at(t)
             derate = faults.derate_at(t)
-            alive = fault_alive
         if watch is not None and watch.armed:
             # armed watchtower feedback: zones flagged for evacuation are
             # masked out exactly like dead fault nodes — stranded services
@@ -538,30 +543,9 @@ class ContinuumRuntime:
             keep = watch.evacuation_mask(t, self._node_regions)
             if keep is not None:
                 alive = keep if alive is None else (alive & keep)
-        if alive is not None:
-            if not alive.all() or derate is not None:
-                low = mask_unavailable(low, alive, derate=derate)
-                problem = problem.with_lowering(low)
-            if self.current:
-                nidx = low.node_index()
-                stranded = [
-                    sid for sid, (_fl, nid) in self.current.items()
-                    if not alive[nidx[nid]]]
-                if stranded:
-                    # a dead node takes its services down with it: the
-                    # incumbent shrinks NOW (accounting must not bill a
-                    # dead node), and re-placement is an emergency
-                    evicted = len(stranded)
-                    for sid in stranded:
-                        del self.current[sid]
-                    emergency = cfg.emergency_replan
-            if (cfg.emergency_replan and not emergency
-                    and derate is not None and self.current):
-                # brownout: the incumbent survived but may no longer fit
-                # the derated capacities — that too forces a replan
-                pl, fc, nc = assignment_arrays(low, self.current)
-                if check_placement(low, pl, fc, nc, alive=alive, t=t):
-                    emergency = True
+        problem, evicted, emergency = self.fault_stage(
+            problem, t, alive, derate)
+        low = problem.lowering
 
         replanned = (t % max(cfg.replan_every, 1) == 0) \
             or self.current is None or emergency
@@ -581,16 +565,8 @@ class ContinuumRuntime:
         t_plan0 = t_plan1 = time.perf_counter()
 
         if replanned:
-            if cfg.oracle:
-                # the oracle stays a TRUE oracle: the degraded view
-                # delegates future_matrix to the raw trace
-                ci_b = self._carbon_view.future_matrix(
-                    self._node_regions, t, cfg.horizon_h)
-            else:
-                ci_b = self._carbon_view.scenario_matrix(
-                    self._node_regions, t, cfg.horizon_h,
-                    cfg.scenarios if cfg.use_whatif else 1)
-            tick_problem = problem.with_scenarios(ScenarioBatch(ci=ci_b))
+            tick_problem = problem.with_scenarios(
+                ScenarioBatch(ci=self.forecast(t)))
             if cfg.warm_start and self.current is not None:
                 tick_problem = tick_problem.with_warm_start(self.current)
             # auto-bucket warmup: record this replan's shape; once the
@@ -638,18 +614,14 @@ class ContinuumRuntime:
         # 5. accounting under the TRUE instantaneous carbon intensity
         t_acct0 = time.perf_counter()
         emissions = 0.0
+        violations: List[PlacementViolation] = []
         placed = fcur = ncur = ci_now = None
         if self.current:
             placed, fcur, ncur = assignment_arrays(low, self.current)
             ci_now = self.carbon.now(self._node_regions, t)
-            emissions = lowered_emissions(
-                low, placed, fcur, ncur, ci=ci_now)
-        # post-plan invariants: the committed assignment must sit on live
-        # nodes within (possibly derated) capacity
-        violations: List[PlacementViolation] = []
-        if cfg.validate_placements and self.current:
-            violations = check_placement(
-                low, placed, fcur, ncur, alive=alive, t=t)
+            emissions, violations = account(
+                low, placed, fcur, ncur, ci_now, alive=alive, t=t,
+                validate=cfg.validate_placements)
             self.placement_violations.extend(violations)
         rec = TickRecord(
             t=t, emissions_g=emissions, migration_g=migration_g,
@@ -685,7 +657,7 @@ class ContinuumRuntime:
                 dmask = faults.dark_at(t)
                 dark = tuple(
                     z for z, d in zip(faults.zones, dmask) if d)
-                stale = bool(self._workload_view.stale(
+                stale = bool(self.workload_view.stale(
                     t, cfg.telemetry_window))
             watch.observe_tick(
                 t, rec, low, placed, fcur, ci_now,
@@ -711,22 +683,91 @@ class ContinuumRuntime:
             tr.add("account", t_acct0, t_acct1, parent=tid)
         return rec
 
-    def _held_output(self, out, t: int):
-        """Telemetry-dropout hold: rebuild the LOWERING inputs (enriched
-        app + Eq. 1/2 profiles) from the newest monitoring whose whole
-        telemetry window is clean, via the estimator's direct path.  The
-        constraint engine keeps the NaN view (fresh constraints empty,
-        KB held under mu-decay); only the priced tensors are held.  The
-        staged scan applies this exact function, so the two paths price
-        identical problems."""
-        monf = self._workload_view.lowering_monitoring(
-            t, self.config.telemetry_window)
+    # -- the tick's stages, shared with the fleet tick and the staging ----
+
+    def ingest(self, t: int):
+        """Point the gatherer's ``signal``/``forecast`` hooks at the carbon
+        view as of ``t``; return the tick's monitoring.  With a fault
+        schedule both views are the DEGRADED world (dark zones report
+        persistence, dropouts deliver NaN samples)."""
+        view, gatherer = self.carbon_view, self.pipeline.gatherer
+        gatherer.signal = view.history_signal(t)
+        gatherer.forecast = view.forecast_signal(t, self.config.horizon_h)
+        return self.workload_view.monitoring(t)
+
+    def constraint_pass(self, mon, t: int, *, enriched=None):
+        """The pipeline's run on ``mon`` (``enriched``: see
+        ``GreenConstraintPipeline.run``), then :meth:`dropout_hold`."""
+        out = self.pipeline.run(self.app, self.infra, mon,
+                                use_kb=self.config.use_kb,
+                                enriched=enriched)
+        held = self.dropout_hold(t)
+        if held is None:
+            return out
+        app, computation, communication = held
+        return dataclasses.replace(out, app=app, computation=computation,
+                                   communication=communication)
+
+    def dropout_hold(self, t: int):
+        """The dropout hold: the LOWERING inputs ``(app, computation,
+        communication)`` from the newest monitoring whose whole window is
+        clean; None when ``t``'s is.  The constraint engine keeps the NaN
+        view (fresh constraints empty, KB mu-decays)."""
+        cfg = self.config
+        if cfg.faults is None \
+                or not self.workload_view.stale(t, cfg.telemetry_window):
+            return None
+        monf = self.workload_view.lowering_monitoring(
+            t, cfg.telemetry_window)
         est = self.pipeline.estimator
-        return dataclasses.replace(
-            out,
-            app=est.enrich(self.app, monf),
-            computation=est.computation_profiles(monf),
-            communication=est.communication_profiles(monf))
+        return (est.enrich(self.app, monf), est.computation_profiles(monf),
+                est.communication_profiles(monf))
+
+    def fault_stage(self, problem, t: int, alive, derate):
+        """Mask dead and derated nodes out of ``problem``'s lowering, evict
+        the services ``self.current`` strands on dead nodes, and decide
+        whether ``t`` is an emergency: ``(problem, evicted, emergency)``,
+        the problem untouched when ``alive`` is None."""
+        cfg = self.config
+        evicted, emergency = 0, False
+        if alive is None:
+            return problem, evicted, emergency
+        low = problem.lowering
+        if not alive.all() or derate is not None:
+            low = mask_unavailable(low, alive, derate=derate)
+            problem = problem.with_lowering(low)
+        if self.current:
+            nidx = low.node_index()
+            stranded = [
+                sid for sid, (_fl, nid) in self.current.items()
+                if not alive[nidx[nid]]]
+            if stranded:
+                # a dead node takes its services down with it: the
+                # incumbent shrinks NOW (accounting must not bill a dead
+                # node), and re-placement is an emergency
+                evicted = len(stranded)
+                for sid in stranded:
+                    del self.current[sid]
+                emergency = cfg.emergency_replan
+        if (cfg.emergency_replan and not emergency
+                and derate is not None and self.current):
+            # brownout: the incumbent survived but may no longer fit the
+            # derated capacities — that too forces a replan
+            pl, fc, nc = assignment_arrays(low, self.current)
+            if check_placement(low, pl, fc, nc, alive=alive, t=t):
+                emergency = True
+        return problem, evicted, emergency
+
+    def forecast(self, t: int):
+        """The forecast ensemble ``ci[B, N]``: the TRUE future under the
+        oracle (the degraded view delegates it), else the scenarios."""
+        cfg = self.config
+        if cfg.oracle:
+            return self.carbon_view.future_matrix(
+                self._node_regions, t, cfg.horizon_h)
+        return self.carbon_view.scenario_matrix(
+            self._node_regions, t, cfg.horizon_h,
+            cfg.scenarios if cfg.use_whatif else 1)
 
     def _record_fault_events(self, obs: Observability, t: int,
                              evicted: int, emergency: bool,
@@ -794,14 +835,8 @@ class ContinuumRuntime:
             reg.gauge("planner.batch", m["planner.batch"], labels=labels)
 
     def run(self, start: int, ticks: int) -> ContinuumResult:
-        gatherer = self.pipeline.gatherer
-        saved = (gatherer.signal, gatherer.forecast)
-        try:
+        with kept_hooks(self.pipeline.gatherer):
             records = [self.tick(t) for t in range(start, start + ticks)]
-        finally:
-            # don't leak the trace's closures into later non-continuum
-            # uses of a shared pipeline (e.g. GreenPlacement.place)
-            gatherer.signal, gatherer.forecast = saved
         return ContinuumResult(ticks=records,
                                final_assignment=dict(self.current or {}))
 

@@ -6,9 +6,12 @@ same adaptive loop as the eager ``run`` — but as ONE jitted
 planner round-trips.  The split of labour:
 
 **Host staging** (exact numpy, one pass over the trace, no objects):
-  * monitoring/carbon ingestion and profile estimation per tick —
-    every per-tick random stream is keyed by ``t`` alone, so the whole
-    trace can be materialized up front without perturbing a single draw;
+  * monitoring/carbon ingestion, profile estimation and the dropout
+    hold per tick, by the eager tick's own stages (``ingest``,
+    ``GreenConstraintPipeline.profiles`` on a copied telemetry buffer,
+    ``dropout_hold``) — every per-tick random stream is keyed by ``t``
+    alone, so the whole trace can be materialized up front without
+    perturbing a single draw;
   * the array constraint engine's refresh -> tau -> survivor pass on a
     COPY of the live cache (incremental dirty-masking continues
     bit-exactly from the runtime's state);
@@ -19,9 +22,10 @@ planner round-trips.  The split of labour:
   * the ranking pass (Eq. 11/12) and the lowering of the kept
     constraints into sparse ``(index, value)`` scatter lists for the
     planner's penalty tensors;
-  * the lowering cache tiers (cache-hit / delta-substitution / full)
-    mirrored against a local cache, producing per-tick ``E``/``order``/
-    edge-energy tensors.
+  * the lowering tiers (:func:`~repro.core.pipeline.resolve_lowering`)
+    against a local mirror of the pipeline's cache, producing per-tick
+    ``E``/``order``/edge-energy tensors, and the tick's forecast
+    ensemble (``ContinuumRuntime.forecast``).
 
 **One jit** (``lax.scan`` over the staged tick tensors): warm-start
 validation -> vmapped branch planner (the exact
@@ -56,9 +60,8 @@ from repro.core.library import (
     AvoidNodeModule,
     TimeShiftModule,
 )
-from repro.core.lowering import lower, lowered_emissions, substitute_profiles
-from repro.core.pipeline import GeneratorOutput, _structural_key
-from repro.core.problem import PlacementProblem
+from repro.core.lowering import lowered_emissions
+from repro.core.pipeline import GeneratorOutput, resolve_lowering
 from repro.core.scheduler import (
     COMPILE_CACHE,
     PLANNER_COMM_ARGC,
@@ -68,7 +71,7 @@ from repro.core.scheduler import (
 from repro.core.types import Affinity, AvoidNode
 from repro.faults import check_placement
 
-from .loop import FallbackReason
+from .loop import FallbackReason, kept_hooks
 from .whatif import assignment_arrays
 
 __all__ = ["run_scanned", "monte_carlo_emissions"]
@@ -192,33 +195,17 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         raise _Fallback(FallbackReason.FAULT_CAPACITY_DERATE, tick=start)
 
     app, infra = runtime.app, runtime.infra
-    # with a fault schedule these are the DEGRADED views (dark zones →
-    # persistence + widened scenarios, dropout ticks → NaN samples);
-    # without one they alias the raw traces.  ``now``/``future_matrix``
-    # delegate to the raw trace either way (truthful accounting).
-    carbon, workload = runtime._carbon_view, runtime._workload_view
     node_regions = runtime._node_regions
-    gatherer, estimator = pipe.gatherer, pipe.estimator
     iter0 = pipe.iteration
     use_kb = bool(cfg.use_kb)
     use_green = bool(scfg.use_green_constraints)
 
-    # telemetry pooling mirror: deep-copy the live ring buffer so staging
-    # stays side-effect free (the staged buffer is handed back at commit)
-    window = int(getattr(pipe, "telemetry_window", 1) or 1)
-    buf = None
-    if window > 1:
-        from repro.learn.telemetry import TelemetryBuffer
-        live_buf = getattr(pipe, "_telemetry", None)
-        if live_buf is not None and live_buf.window == window:
-            buf = copy.deepcopy(live_buf)
-        else:
-            buf = TelemetryBuffer(window=window)
-
     st = _Staged()
     st.T, st.iter0 = T, iter0
     st.eng, st.use_kb, st.use_green = eng, use_kb, use_green
-    st.buf, st.window = buf, window
+    # telemetry pooling mirror: a deep copy of the live ring buffer, so
+    # staging leaves its contents as they were (handed back at commit)
+    st.buf = buf = copy.deepcopy(pipe.telemetry_buffer())
 
     scache = None
     lcache = pipe._lowering_cache
@@ -252,33 +239,17 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         t = start + k
         it = iter0 + k + 1
 
-        # -- tick ingestion: identical hook/profile sequence to tick() --
+        # -- tick ingestion: the eager tick's stages, on the staged
+        # telemetry buffer; the engine below keeps a dropout's NaN view,
+        # the LOWERING prices the held profiles
         t_ingest = time.perf_counter()
-        gatherer.signal = carbon.history_signal(t)
-        gatherer.forecast = carbon.forecast_signal(t, cfg.horizon_h)
-        mon = workload.monitoring(t)
-        infra_e = gatherer.enrich(infra)
-        app_e = estimator.enrich(app, mon)
-        comp = estimator.computation_profiles(mon)
-        commu = estimator.communication_profiles(mon)
-        if buf is not None:
-            buf.ingest(it, mon, infra_e)
-            comp = buf.computation_profiles(last=window)
-            commu = buf.communication_profiles(last=window)
+        mon = runtime.ingest(t)
+        app_e, infra_e, comp, commu = pipe.profiles(app, infra, mon, buf, it)
         comps.append(comp)
         commus.append(commu)
         infras.append(infra_e)
-
-        # telemetry-dropout hold: the engine below keeps the NaN view
-        # (fresh constraints come up empty, KB mu-decays), but the
-        # LOWERING prices the last clean window's profiles — the same
-        # estimator direct path the eager tick's _held_output applies
-        app_low, comp_low, commu_low = app_e, comp, commu
-        if faults is not None and workload.stale(t, window):
-            monf = workload.lowering_monitoring(t, window)
-            app_low = estimator.enrich(app, monf)
-            comp_low = estimator.computation_profiles(monf)
-            commu_low = estimator.communication_profiles(monf)
+        app_low, comp_low, commu_low = (
+            runtime.dropout_hold(t) or (app_e, comp, commu))
 
         # -- constraint engine: refresh + survivors on the staged cache --
         t_engine = time.perf_counter()
@@ -351,23 +322,8 @@ def _stage(runtime, start: int, T: int) -> _Staged:
         t_lower = time.perf_counter()
         out = GeneratorOutput(constraints=(), app=app_low, infra=infra_e,
                               computation=comp_low, communication=commu_low)
-        key = ("auto", PlacementProblem.cache_key(out))
-        if lcache is not None and lcache[0] == key:
-            low = lcache[2]
-            path = "cache_hit"
-        else:
-            skey_l = ("auto", _structural_key(out)) \
-                if pipe.delta_substitution else None
-            if lcache is not None and skey_l is not None \
-                    and lcache[1] == skey_l:
-                low = substitute_profiles(
-                    lcache[2], app_low, infra_e, comp_low, commu_low)
-                path = "delta"
-            else:
-                low = lower(app_low, infra_e, comp_low, commu_low,
-                            backend="auto")
-                path = "full"
-            lcache = (key, skey_l, low)
+        low, path, lcache = resolve_lowering(
+            lcache, out, "auto", pipe.delta_substitution)
         paths.append(path)
         path_counts[path] += 1
         lows.append(low)
@@ -495,17 +451,11 @@ def _stage(runtime, start: int, T: int) -> _Staged:
 
         # -- forecast ensemble + true-CI tensors -------------------------
         t_forecast = time.perf_counter()
-        if cfg.oracle:
-            ci_b = carbon.future_matrix(node_regions, t, cfg.horizon_h)
-        else:
-            ci_b = carbon.scenario_matrix(
-                node_regions, t, cfg.horizon_h,
-                cfg.scenarios if cfg.use_whatif else 1)
-        ci_b = np.asarray(ci_b, float)
+        ci_b = np.asarray(runtime.forecast(t), float)
         ci_b_t.append(ci_b)
         ci_mean_t.append(ci_b.mean(axis=1))
         ci_now_t.append(np.asarray(
-            carbon.now(node_regions, t), float))
+            runtime.carbon.now(node_regions, t), float))
         replan_t.append(t % max(cfg.replan_every, 1) == 0)
         # node liveness rides the scan as a [T, N] mask (all-ones without
         # a schedule — the program shape is fault-agnostic); dead nodes
@@ -1340,16 +1290,17 @@ def run_scanned(runtime, start: int, ticks: int):
         return ContinuumResult(
             ticks=[], final_assignment=dict(runtime.current or {}))
     watch = getattr(runtime, "watch", None)
-    gatherer = runtime.pipeline.gatherer
-    saved = (gatherer.signal, gatherer.forecast)
     t0 = time.perf_counter()
     try:
-        if watch is not None and watch.armed:
-            # armed feedback (alert -> zone evacuation -> replan) is
-            # data-dependent control flow the staged scan cannot
-            # express; observe-mode watchers ride the scan natively
-            raise _Fallback(FallbackReason.WATCH_ARMED, tick=start)
-        st = _stage(runtime, start, ticks)
+        # the trace's closures are restored BEFORE any eager fallback
+        # replay (which re-points and re-restores them itself)
+        with kept_hooks(runtime.pipeline.gatherer):
+            if watch is not None and watch.armed:
+                # armed feedback (alert -> zone evacuation -> replan) is
+                # data-dependent control flow the staged scan cannot
+                # express; observe-mode watchers ride the scan natively
+                raise _Fallback(FallbackReason.WATCH_ARMED, tick=start)
+            st = _stage(runtime, start, ticks)
     except _Fallback as fb:
         runtime.last_scanned_fallback = fb.reason
         ev = FallbackEvent(
@@ -1361,10 +1312,6 @@ def run_scanned(runtime, start: int, ticks: int):
             obs.registry.event("runtime.scanned_fallback", tick=ev.tick,
                                reason=ev.reason, detail=ev.detail)
         st = None
-    finally:
-        # never leak the trace's closures — restored BEFORE any eager
-        # fallback replay (which re-points and re-restores them itself)
-        gatherer.signal, gatherer.forecast = saved
     if st is None:
         return runtime.run(start, ticks)
     stage_s = time.perf_counter() - t0
@@ -1446,15 +1393,12 @@ def monte_carlo_emissions(runtime, start: int, ticks: int, ci_scales):
     ticks = int(ticks)
     if ticks <= 0:
         raise ValueError("monte_carlo_emissions needs ticks > 0")
-    gatherer = runtime.pipeline.gatherer
-    saved = (gatherer.signal, gatherer.forecast)
     try:
-        st = _stage(runtime, start, ticks)
+        with kept_hooks(runtime.pipeline.gatherer):
+            st = _stage(runtime, start, ticks)
     except _Fallback as fb:
         raise ValueError(
             f"trace cannot be staged for the fused loop: {fb.reason}")
-    finally:
-        gatherer.signal, gatherer.forecast = saved
 
     import jax
 

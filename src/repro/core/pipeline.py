@@ -66,6 +66,37 @@ def _structural_key(out: "GeneratorOutput") -> Tuple:
     )
 
 
+LoweringCache = Tuple[tuple, Optional[tuple], LoweredProblem]
+
+# lowering path -> the ``lowering_stats`` counter it bumps
+_LOWERING_STATS = {"cache_hit": "cache_hits", "delta": "delta_substitutions",
+                   "full": "full_lowers"}
+
+
+def resolve_lowering(cache: Optional[LoweringCache], out: "GeneratorOutput",
+                     backend: str, delta: bool
+                     ) -> Tuple[LoweredProblem, str, LoweringCache]:
+    """The lowering tiers against a one-slot ``cache`` ``(full_key,
+    structural_key, lowering)``, cheapest first: ``"cache_hit"`` (inputs
+    value-identical: the cached object), ``"delta"`` (only ``ci[N]`` /
+    ``E[S, F]`` moved, and ``delta`` on: those array-substituted into the
+    cached lowering, O(S*F + N)), ``"full"`` (:func:`lower`).  Returns
+    ``(lowering, path, new_cache)``; mutates nothing."""
+    key = (backend, PlacementProblem.cache_key(out))
+    if cache is not None and cache[0] == key:
+        return cache[2], "cache_hit", cache
+    skey = (backend, _structural_key(out)) if delta else None
+    if cache is not None and skey is not None and cache[1] == skey:
+        low = substitute_profiles(cache[2], out.app, out.infra,
+                                  out.computation, out.communication)
+        path = "delta"
+    else:
+        low = lower(out.app, out.infra, out.computation, out.communication,
+                    backend=backend)
+        path = "full"
+    return low, path, (key, skey, low)
+
+
 @dataclass
 class GeneratorOutput:
     constraints: Sequence[Constraint]      # ranked, weighted, filtered
@@ -140,8 +171,7 @@ class GreenConstraintPipeline:
     # so a structural-only match takes the substitution fast path.
     # Constraints are part of neither: they ride on the problem, not the
     # lowering.
-    _lowering_cache: Optional[
-        Tuple[tuple, Optional[tuple], LoweredProblem]] = field(
+    _lowering_cache: Optional[LoweringCache] = field(
         default=None, repr=False, compare=False)
     # Observability: how each problem_for call resolved its lowering.
     lowering_stats: Dict[str, int] = field(
@@ -181,21 +211,9 @@ class GreenConstraintPipeline:
         that runs many pipelines on one infrastructure and carbon signal
         (the fleet tick); given, the gatherer is not called."""
         self.iteration += 1
-        infra = self.gatherer.enrich(infra) if enriched is None else enriched
-        app = self.estimator.enrich(app, monitoring)
-        computation = self.estimator.computation_profiles(monitoring)
-        communication = self.estimator.communication_profiles(monitoring)
-        if self.telemetry_window > 1:
-            from repro.learn.telemetry import TelemetryBuffer
-            buf = self._telemetry
-            if buf is None or buf.window != self.telemetry_window:
-                buf = TelemetryBuffer(window=self.telemetry_window)
-                self._telemetry = buf
-            buf.ingest(self.iteration, monitoring, infra)
-            computation = buf.computation_profiles(
-                last=self.telemetry_window)
-            communication = buf.communication_profiles(
-                last=self.telemetry_window)
+        app, infra, computation, communication = self.profiles(
+            app, infra, monitoring, self.telemetry_buffer(), self.iteration,
+            enriched=enriched)
 
         t0 = time.perf_counter()
         if self.engine == "reference":
@@ -249,6 +267,35 @@ class GreenConstraintPipeline:
             computation=computation,
             communication=communication,
         )
+
+    def profiles(self, app: Application, infra: Infrastructure,
+                 monitoring: MonitoringData, buffer, iteration: int, *,
+                 enriched: Optional[Infrastructure] = None) -> Tuple:
+        """One iteration's profile ingest: the gatherer's enrichment of
+        ``infra`` (``enriched`` given: skipped), the estimator's of ``app``
+        and its Eq. 1/2 profiles, pooled through ``buffer`` (None: this
+        window alone) at ``iteration``.  Mutates only ``buffer``."""
+        infra = self.gatherer.enrich(infra) if enriched is None else enriched
+        app = self.estimator.enrich(app, monitoring)
+        computation = self.estimator.computation_profiles(monitoring)
+        communication = self.estimator.communication_profiles(monitoring)
+        if buffer is not None:
+            buffer.ingest(iteration, monitoring, infra)
+            computation = buffer.computation_profiles(last=buffer.window)
+            communication = buffer.communication_profiles(
+                last=buffer.window)
+        return app, infra, computation, communication
+
+    def telemetry_buffer(self):
+        """The pooling ring for ``telemetry_window`` > 1 (else None), made
+        anew when missing or of another window."""
+        w = self.telemetry_window
+        if w <= 1:
+            return None
+        if self._telemetry is None or self._telemetry.window != w:
+            from repro.learn.telemetry import TelemetryBuffer
+            self._telemetry = TelemetryBuffer(window=w)
+        return self._telemetry
 
     # -- constraint-pass plumbing -------------------------------------------
 
@@ -351,43 +398,17 @@ class GreenConstraintPipeline:
                     backend: str = "auto") -> PlacementProblem:
         """Fold one pipeline iteration into a :class:`PlacementProblem`.
 
-        Three resolution tiers, cheapest first (counted in
-        ``lowering_stats``):
-
-        1. *cache hit* — the lowering inputs are value-identical to the
-           cached tick: reuse the lowering object untouched;
-        2. *delta substitution* — only ``ci[N]`` / ``E[S, F]`` moved
-           (same structure, same masks): array-substitute the drifting
-           profiles into the cached lowering
-           (:func:`~repro.core.lowering.substitute_profiles`, O(S*F + N)
-           instead of the full object walk);
-        3. *full lower* — anything structural changed.
-
-        The problem's constraints always come fresh from ``out`` — KB
+        The lowering comes from :func:`resolve_lowering`'s tiers against
+        the pipeline's one-slot cache, each path counted in
+        ``lowering_stats`` and the registry's ``lowering.path``.  The
+        problem's constraints always come fresh from ``out`` — KB
         memory decay re-weights them every tick without touching the
         lowering.
         """
-        key = (backend, PlacementProblem.cache_key(out))
-        cache = self._lowering_cache
-        if cache is not None and cache[0] == key:
-            low = cache[2]
-            self.lowering_stats["cache_hits"] += 1
-            _REGISTRY.inc("lowering.path", labels={"path": "cache_hit"})
-        else:
-            skey = (backend, _structural_key(out)) \
-                if self.delta_substitution else None
-            if cache is not None and skey is not None and cache[1] == skey:
-                low = substitute_profiles(
-                    cache[2], out.app, out.infra, out.computation,
-                    out.communication)
-                self.lowering_stats["delta_substitutions"] += 1
-                _REGISTRY.inc("lowering.path", labels={"path": "delta"})
-            else:
-                low = lower(out.app, out.infra, out.computation,
-                            out.communication, backend=backend)
-                self.lowering_stats["full_lowers"] += 1
-                _REGISTRY.inc("lowering.path", labels={"path": "full"})
-            self._lowering_cache = (key, skey, low)
+        low, path, self._lowering_cache = resolve_lowering(
+            self._lowering_cache, out, backend, self.delta_substitution)
+        self.lowering_stats[_LOWERING_STATS[path]] += 1
+        _REGISTRY.inc("lowering.path", labels={"path": path})
         # Pass the constraints through as-is: a lazy ConstraintSet stays
         # columnar all the way into lower_constraints (no per-constraint
         # clone), and PlacementProblem.__post_init__ keeps it un-tupled.

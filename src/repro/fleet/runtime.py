@@ -71,10 +71,12 @@ from repro.continuum.loop import (
     GateDecision,
     RuntimeConfig,
     TickRecord,
+    account,
+    kept_hooks,
 )
 from repro.continuum.traces import CarbonTrace, WorkloadTrace
 from repro.continuum.whatif import assignment_arrays, plan_assignment
-from repro.core.lowering import lowered_emissions, mask_unavailable
+from repro.core.lowering import lowered_emissions
 from repro.faults import PlacementViolation, check_placement
 from repro.core.problem import BucketSpec
 from repro.core.scheduler import (
@@ -242,12 +244,13 @@ class FleetRuntime:
         misses0 = COMPILE_CACHE.misses
         t_tick0 = time.perf_counter()
 
-        # 1+2. per-tenant ingestion + constraint pipeline -> one problem
-        # per app, warm-started from its incumbent.  With a fault
-        # schedule the ingestion goes through each runtime's degraded
-        # views, dead/derated nodes are masked out of every tenant's
-        # lowering, and stranded services are evicted (re-placement is
-        # an emergency that bypasses the per-app hysteresis gate).
+        # 1+2. each tenant runtime's ingest, constraint pass and fault
+        # stage -> one problem per app, warm-started from its incumbent.
+        # With a fault schedule the ingestion goes through each runtime's
+        # degraded views, dead/derated nodes are masked out of every
+        # tenant's lowering, and stranded services are evicted
+        # (re-placement is an emergency that bypasses the per-app
+        # hysteresis gate).
         faults = cfg.faults
         alive = faults.alive_at(t) if faults is not None else None
         derate = faults.derate_at(t) if faults is not None else None
@@ -266,46 +269,18 @@ class FleetRuntime:
         t0 = t_tick0
         for fa in self.apps:
             rt = self._runtimes[fa.name]
-            view, gatherer = rt._carbon_view, rt.pipeline.gatherer
-            gatherer.signal = view.history_signal(t)
-            gatherer.forecast = view.forecast_signal(t, cfg.horizon_h)
+            mon = rt.ingest(t)
+            view, gatherer = rt.carbon_view, rt.pipeline.gatherer
             key = (id(view), type(gatherer), gatherer.window,
                    gatherer.forecast_from_history)
             if key not in enriched:
                 # the view is held so that its id stays its own all tick
                 enriched[key] = (view, gatherer.enrich(self.infra))
-            mon = rt._workload_view.monitoring(t)
             t1 = time.perf_counter()
-            out = rt.pipeline.run(fa.app, self.infra, mon,
-                                  use_kb=cfg.use_kb,
-                                  enriched=enriched[key][1])
-            if faults is not None \
-                    and rt._workload_view.stale(t, cfg.telemetry_window):
-                out = rt._held_output(out, t)
+            out = rt.constraint_pass(mon, t, enriched=enriched[key][1])
             t2 = time.perf_counter()
-            problem = rt.pipeline.problem_for(out)
-            evicted[fa.name] = 0
-            emergency[fa.name] = False
-            if faults is not None:
-                low = problem.lowering
-                if not alive.all() or derate is not None:
-                    low = mask_unavailable(low, alive, derate=derate)
-                    problem = problem.with_lowering(low)
-                if rt.current:
-                    nidx = low.node_index()
-                    stranded = [
-                        sid for sid, (_fl, nid) in rt.current.items()
-                        if not alive[nidx[nid]]]
-                    for sid in stranded:
-                        del rt.current[sid]
-                    if stranded:
-                        evicted[fa.name] = len(stranded)
-                        emergency[fa.name] = cfg.emergency_replan
-                if (cfg.emergency_replan and not emergency[fa.name]
-                        and derate is not None and rt.current):
-                    pl, fc, nc = assignment_arrays(low, rt.current)
-                    if check_placement(low, pl, fc, nc, alive=alive, t=t):
-                        emergency[fa.name] = True
+            problem, evicted[fa.name], emergency[fa.name] = rt.fault_stage(
+                rt.pipeline.problem_for(out), t, alive, derate)
             if cfg.warm_start and rt.current is not None:
                 problem = problem.with_warm_start(rt.current)
             problems.append(problem)
@@ -411,19 +386,15 @@ class FleetRuntime:
             viols: List[PlacementViolation] = []
             if rt.current:
                 placed, fcur, ncur = assignment_arrays(low, rt.current)
-                emissions = lowered_emissions(
-                    low, placed, fcur, ncur, ci=ci_now)
                 accumulate_loads(low, placed, fcur, ncur,
                                  cpu_load, ram_load)
-                if cfg.validate_placements:
-                    # liveness per tenant here; capacity runs once on
-                    # the SUMMED loads after every tenant is accounted
-                    viols = check_placement(
-                        low, placed, fcur, ncur,
-                        alive=alive if faults is not None else None,
-                        t=t, cpu_load=np.zeros(low.N),
-                        ram_load=np.zeros(low.N))
-                    self.placement_violations.extend(viols)
+                # liveness per tenant here; capacity runs once on the
+                # SUMMED loads after every tenant is accounted
+                emissions, viols = account(
+                    low, placed, fcur, ncur, ci_now, alive=alive, t=t,
+                    validate=cfg.validate_placements,
+                    cpu_load=np.zeros(low.N), ram_load=np.zeros(low.N))
+                self.placement_violations.extend(viols)
             records[fa.name] = TickRecord(
                 t=t, emissions_g=emissions, migration_g=migration_g,
                 migrations=migrations, replanned=True, switched=switched,
@@ -576,18 +547,9 @@ class FleetRuntime:
                 if r.plans[0].feasible}
 
     def run(self, start: int, ticks: int) -> FleetRunResult:
-        saved = {
-            name: (rt.pipeline.gatherer.signal,
-                   rt.pipeline.gatherer.forecast)
-            for name, rt in self._runtimes.items()}
-        try:
+        with kept_hooks(*(rt.pipeline.gatherer
+                          for rt in self._runtimes.values())):
             frecs = [self.tick(t) for t in range(start, start + ticks)]
-        finally:
-            # don't leak the trace's closures into later uses of the
-            # per-app pipelines (mirrors ContinuumRuntime.run)
-            for name, rt in self._runtimes.items():
-                (rt.pipeline.gatherer.signal,
-                 rt.pipeline.gatherer.forecast) = saved[name]
         results = {
             fa.name: ContinuumResult(
                 ticks=[fr.records[fa.name] for fr in frecs],

@@ -433,7 +433,7 @@ class Watchtower:
             if faults is not None:
                 dmask = faults.dark_at(t)
                 dark = tuple(z for z, d in zip(faults.zones, dmask) if d)
-                stale = bool(runtime._workload_view.stale(
+                stale = bool(runtime.workload_view.stale(
                     t, cfg.telemetry_window))
             alerts += self._freshness(t, dark, stale)
             ci_mean = float(np.mean(st.ci_now[k]))

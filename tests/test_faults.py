@@ -30,10 +30,12 @@ from repro.faults import (
 from repro.fleet import FleetApp, FleetRuntime
 from repro.continuum import (
     CarbonTrace,
+    ContinuumRuntime,
     REGION_PRESETS,
     RuntimeConfig,
     WorkloadTrace,
 )
+from repro.core.problem import PlacementProblem
 from repro.obs import Observability
 
 REGIONS = ("solar-south", "wind-north", "coal-east")
@@ -477,3 +479,80 @@ def test_fleet_outage_evicts_atomically_and_stays_feasible():
     # the summed multi-tenant load) held every tick
     assert frt.placement_violations == []
     assert all(r.violations == 0 for recs in per_app for r in recs)
+
+
+# the fault tick of the fleet/eager fault-stage comparison, after a
+# fault-free warm-up; the green planner keeps off coal-east, so faults on
+# every other node reach the incumbent
+_FAULT_TICK = 4
+_NOT_COAL = ("solar-south-0", "solar-south-1", "wind-north-0",
+             "wind-north-1")
+
+
+@pytest.mark.parametrize("case", ["outage", "derate", "dropout"])
+def test_fleet_fault_stage_matches_the_eager_tick(case, monkeypatch):
+    """A tenant's fault stage in a FleetRuntime tick is the eager tick's:
+    on the same trace, tick and incumbent, the lowering each hands its
+    planner (dead nodes masked, capacities derated, dropout profiles
+    held), the services evicted and the emergency verdict are equal."""
+    import repro.fleet.runtime as fleet_runtime
+
+    _, infra = _scenario(n_services=2)
+    t = _FAULT_TICK
+    events = {
+        "outage": [FaultEvent("node_outage", n, t, 2) for n in _NOT_COAL],
+        "derate": [FaultEvent("capacity_derate", n, t, 2, 0.1)
+                   for n in _NOT_COAL],
+        "dropout": [FaultEvent("telemetry_dropout", "", t, 2)],
+    }[case]
+    ft = FaultTrace.from_events(_node_ids(infra), REGIONS, t + 2, events)
+    carbon = CarbonTrace(REGION_PRESETS, hours=t + 30, seed=3)
+    app = _tenant("ta", 3)
+    workload = WorkloadTrace(app, seed=0, noise=0.0)
+    cfg = RuntimeConfig(horizon_h=4, faults=ft)
+    fleet = FleetRuntime([FleetApp(app.name, app, workload)], infra, carbon,
+                         config=cfg)
+    eager = ContinuumRuntime(app, infra, carbon, workload, config=cfg)
+    for k in range(t):
+        fleet.tick(k)
+        eager.tick(k)
+    tenant = fleet.runtime(app.name)
+    assert tenant.current
+    eager.current = dict(tenant.current)
+
+    seen = {}
+    plan_many = fleet_runtime.plan_many
+
+    def fleet_plan(problem, *args, **kwargs):
+        seen["fleet"] = problem.apps[0].lowering
+        return plan_many(problem, *args, **kwargs)
+
+    evaluate = eager.planner.evaluate
+
+    def eager_plan(problem):
+        seen["eager"] = problem.lowering
+        return evaluate(problem)
+
+    monkeypatch.setattr(fleet_runtime, "plan_many", fleet_plan)
+    monkeypatch.setattr(eager.planner, "evaluate", eager_plan)
+    rec_f = fleet.tick(t).records[app.name]
+    rec_e = eager.tick(t)
+
+    def key(low):
+        return PlacementProblem(lowering=low, constraints=()).fingerprint
+
+    assert key(seen["fleet"]) == key(seen["eager"])
+    assert (rec_f.evicted, rec_f.emergency) == \
+        (rec_e.evicted, rec_e.emergency)
+    low = seen["eager"]
+    dead = np.isin(low.node_ids, _NOT_COAL)
+    if case == "outage":
+        assert rec_e.evicted > 0 and rec_e.emergency
+        assert (np.asarray(low.avail_cap)[dead] < 0).all()
+    elif case == "derate":
+        assert rec_e.evicted == 0 and rec_e.emergency
+        assert (np.asarray(low.cpu_cap)[dead] == 0.5).all()
+    else:
+        assert rec_e.evicted == 0 and not rec_e.emergency
+        # the lowering prices the last clean window, not the NaN samples
+        assert np.isfinite(low.E).all()
